@@ -13,8 +13,9 @@ report (static vs adaptive vs mesh-sharded tokens/s, TTFT p50/p95,
 achieved bandwidth per tier, per-run ``mesh_shape``, per-link fetch-once
 traffic vs the multicast oracle) — the ``BENCH_serving.json`` artifact CI
 uploads so the serving perf trajectory is tracked across PRs.  The
-sharded run's device count comes from ``BENCH_MESH_DEVICES`` (default 2;
-it spawns a subprocess with a forced multi-device host platform).
+sharded run uses ``min(BENCH_MESH_DEVICES, device count)`` devices of this
+process (default 2) and is left out on one device; on a CPU host, force
+devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 """
 from __future__ import annotations
 

@@ -4,8 +4,8 @@ trace-driven scheduler scenarios.
 Runs the end-to-end serving driver four ways — the static plan, the
 adaptive runtime, a chaos run with a mid-trace HBM shrink (the
 never-OOM elastic-degradation acceptance: failed_requests must be 0),
-and (in a subprocess with a forced multi-device host platform) the
-mesh-sharded engine — and emits both the CSV rows the
+and, when more than one device is present, the mesh-sharded engine — and
+emits both the CSV rows the
 benchmark harness prints and the machine-readable ``BENCH_serving.json``
 payload (``benchmarks.run --json-out``), so the serving perf trajectory
 (tokens/s, TTFT percentiles, achieved bandwidth per tier, static vs
@@ -22,16 +22,16 @@ tests); only the latency distribution moves.
 
 Every per-run report carries a ``mesh_shape`` field; the sharded run adds
 ``mesh_traffic`` (per-link fetch-once bytes vs the multicast oracle).
-The sharded row needs ``XLA_FLAGS=--xla_force_host_platform_device_count``
-set *before* jax initializes, so it runs ``repro.launch.serve`` in a
-fresh interpreter; a failure there degrades to a stderr warning rather
-than sinking the section.
+The sharded row runs in this process on the first
+``min(BENCH_MESH_DEVICES, device count)`` devices, and only when that is
+more than one; on a CPU host, force devices with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before starting.
+A failure there fails the section like any other row.
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import tempfile
 from typing import Iterable
@@ -102,35 +102,18 @@ def _scenario_rows(scenarios: dict) -> list[Row]:
 
 
 def _sharded_report(n_devices: int) -> dict | None:
-    """Run the serving driver on an n-device mesh in a subprocess.
+    """Serve ``ARGS`` on a mesh of the first ``n_devices`` devices present
+    (capped at the device count).  Fewer than two is no sharded row — a
+    1-device serve is just the static row and must not be labeled
+    sharded."""
+    import jax
 
-    ``n_devices <= 1`` skips the run (BENCH_MESH_DEVICES=0/1 is the
-    opt-out) — a 1-device serve is just the static row and must not be
-    labeled sharded."""
-    if n_devices <= 1:
+    from repro.launch.serve import main as serve_main
+
+    n = min(n_devices, jax.device_count())
+    if n <= 1:
         return None
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={n_devices} " + flags).strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(root, "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    with tempfile.TemporaryDirectory() as tmp:
-        out = os.path.join(tmp, "bench.json")
-        cmd = [sys.executable, "-m", "repro.launch.serve", *ARGS,
-               "--mesh-devices", str(n_devices), "--bench-json", out]
-        try:
-            subprocess.run(cmd, env=env, cwd=root, check=True,
-                           capture_output=True, timeout=1200)
-            with open(out) as fh:
-                return json.load(fh)
-        except (subprocess.SubprocessError, OSError, json.JSONDecodeError) as exc:
-            stderr = getattr(exc, "stderr", b"") or b""
-            tail = stderr[-2000:].decode("utf-8", "replace") if stderr else ""
-            print(f"# serving sharded row skipped: {exc}\n{tail}",
-                  file=sys.stderr)
-            return None
+    return serve_main(ARGS + ["--mesh-devices", str(n), "--bench-json", ""])
 
 
 def collect() -> tuple[list[Row], dict]:
@@ -146,7 +129,7 @@ def collect() -> tuple[list[Row], dict]:
     runs: list[tuple[str, dict]] = [("static", static), ("adaptive", adaptive),
                                     ("chaos_shrink", chaos)]
     if sharded is not None:
-        runs.append((f"sharded_{SHARDED_DEVICES}dev", sharded))
+        runs.append((f"sharded_{sharded['mesh_shape'][0]}dev", sharded))
     rows: list[Row] = []
     for name, rep in runs:
         tps = rep["tokens_per_s"]

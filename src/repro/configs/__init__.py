@@ -6,6 +6,7 @@ for CPU smoke tests). ``get(name)`` resolves either.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib
 
 from repro.configs.base import SHAPES, ModelConfig, ShapeConfig, cell_applicable
@@ -34,8 +35,10 @@ def get(name: str) -> ModelConfig:
 
 
 def get_smoke(name: str) -> ModelConfig:
+    """The reduced config.  Smoke configs are the CPU test substrate and
+    compute in float32, so tests can compare paths exactly."""
     mod = importlib.import_module(f"repro.configs.{_ALIAS.get(name, name)}")
-    return mod.SMOKE
+    return dataclasses.replace(mod.SMOKE, dtype="float32")
 
 
 __all__ = ["ARCH_IDS", "PAPER_IDS", "SHAPES", "ModelConfig", "ShapeConfig",

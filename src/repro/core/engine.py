@@ -98,8 +98,7 @@ class TieringPlan:
     prefill_op_ratios: dict[str, float] | None = None  # prefill-phase solve
     mesh: MeshPlan | None = None           # device axis (None = single chip)
 
-    def partition(self, params: dict[str, Any], *, align: int = 1,
-                  place_remote: bool = False) -> dict[str, Any]:
+    def partition(self, params: dict[str, Any], *, align: int = 1) -> dict[str, Any]:
         """Realize the plan on a params pytree (the unified tiering API).
 
         Every operand in the registry whose planner op carries a non-zero
@@ -115,11 +114,10 @@ class TieringPlan:
         The physical split follows the *decode-phase* ratios: a weight can
         only live in one place, and decode is the steady state — prefill
         streams the same remote partitions (see ``prefill_op_ratios`` for
-        the prefill-phase accounting solve).  With ``place_remote`` the
-        remote tier is pinned to host memory on backends that support it.
-        Under a mesh plan every remote extent is additionally rounded to a
-        multiple of ``mesh.n_devices`` so the host-resident shard splits
-        into equal 1/P slices, one per host link.
+        the prefill-phase accounting solve).  Under a mesh plan every
+        remote extent is additionally rounded to a multiple of
+        ``mesh.n_devices`` so the host-resident shard splits into equal 1/P
+        slices, one per host link.
         """
         out = _copy_tree(params)
         for od in self.registry:
@@ -133,10 +131,8 @@ class TieringPlan:
             _, n_remote = tiering.split_sizes(leaf.shape[od.axis], ratio, align_eff)
             if n_remote == 0:
                 continue
-            t = tiering.partition(leaf, ratio, axis=od.axis, align=align_eff)
-            if place_remote:
-                t = tiering.place(t)
-            _set_path(out, od.path, t)
+            _set_path(out, od.path,
+                      tiering.partition(leaf, ratio, axis=od.axis, align=align_eff))
         return out
 
 

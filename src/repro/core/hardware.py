@@ -83,6 +83,21 @@ RTX6000_BLACKWELL = HardwareSpec(
 
 SYSTEMS = {s.name: s for s in (TPU_V5E, GH200, RTX6000_BLACKWELL)}
 
+# Accelerators this repo has a spec for, keyed by ``jax.Device.device_kind``
+# as JAX reports it on that chip.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device) -> HardwareSpec:
+    """The spec of a JAX device.  A device kind not in `DEVICE_KINDS` is an
+    error: there is no default chip."""
+    kind = getattr(device, "device_kind", None)
+    if device.platform != "tpu" or kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"no hardware spec for {device.platform} device kind {kind!r} "
+            f"(known TPU kinds: {sorted(DEVICE_KINDS)})")
+    return DEVICE_KINDS[kind]
+
 
 def optimal_memory_bound_ratio(hw: HardwareSpec) -> float:
     """Paper §4.2.1: memory-bound EB peaks at B_h / (B_h + B_g)."""

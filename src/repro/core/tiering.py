@@ -1,13 +1,14 @@
 """Tiered arrays — paper §4.1 data partition (Fig. 5a).
 
-A matrix operand is split along one axis into a *local* (HBM) part and a
-*remote* (host) part.  Weights split along the output-row (M) dimension;
-KV caches split along batch (decode) or sequence (long-context split-K).
+A matrix operand is split along one axis into a *local* part and a
+*remote* part.  Weights split along the output-row (M) dimension; KV caches
+split along batch (decode) or sequence (long-context split-K).
 
-On a real TPU runtime the remote part is placed with
-``memory_kind="pinned_host"`` so XLA streams it over the host link; on
-backends without host memory-kinds (CPU CI) the placement is carried as
-metadata and the traffic model (`core/ebmodel.py`) does the accounting.
+Both parts are device (HBM) arrays today.  The paper reads the remote part
+from host memory directly, but on v5e with JAX 0.9 / libtpu 0.0.34 a Pallas
+operand in host memory does not compile (`kernels.splitk_gemm` has the
+details), so the remote tier is a separate HBM buffer that the kernels and
+the traffic model (`core/ebmodel.py`) treat as host-resident.
 `TieredArray` is a pytree, so it flows through jit/pjit/scan unchanged.
 """
 from __future__ import annotations
@@ -118,21 +119,6 @@ def matmul(x: jax.Array, w: Any) -> jax.Array:
                 f"(axis=-1), got axis={w.axis} for shape {w.shape}")
         return jnp.concatenate([x @ w.local, x @ w.remote], axis=-1)
     return x @ w
-
-
-def place(t: TieredArray, device: Any | None = None) -> TieredArray:
-    """Pin the remote part to host memory when the backend supports it.
-
-    TPU runtimes expose ``memory_kind='pinned_host'`` shardings; CPU does
-    not, in which case placement is a no-op (tier is tracked logically).
-    """
-    try:
-        dev = device or jax.devices()[0]
-        sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
-        remote = jax.device_put(t.remote, sharding)
-        return TieredArray(local=t.local, remote=remote, axis=t.axis)
-    except (ValueError, RuntimeError, TypeError):
-        return t
 
 
 def partition_tree(

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
@@ -121,7 +120,7 @@ def flash_prefill(
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((b, h, tq, hd), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -3,8 +3,15 @@
 `tiered_matmul` / `tiered_decode_attention` are the drop-in compute ops the
 serving engine uses (the JAX analogue of the paper's SplitK_GEMM /
 SplitK_FlashAttn PyTorch modules).  They handle shape alignment ("execution
-wave alignment", paper §4.1), pick interpret mode automatically off-TPU, and
-fall back to the jnp oracle for shapes the kernels do not cover.
+wave alignment", paper §4.1), run the kernels in interpret mode when the
+backend is not a TPU (the CPU test substrate), and fall back to the jnp
+oracle for shapes the kernels do not cover.  Inside `count_dispatch()`
+every dispatch decision is counted (``kernel`` / ``jnp`` per op) as the
+program is traced, so a caller can see how many tiered operands of a
+compiled program took the kernel and how many took the jnp path.
+
+Both tiers are HBM-resident ``pl.ANY`` operands on this toolchain: a
+``pltpu.HOST`` operand does not compile on v5e (see `kernels.splitk_gemm`).
 
 ``window`` — the number of in-flight remote-DMA slots — is a *per-call*
 value: the serving engine threads the adaptive runtime's AIMD-controlled
@@ -22,6 +29,10 @@ params tree in one ``shard_map``, called each step by
 """
 from __future__ import annotations
 
+import contextlib
+from collections import Counter
+from contextvars import ContextVar
+
 import jax
 import jax.numpy as jnp
 
@@ -38,6 +49,27 @@ from repro.kernels.splitk_gemm import (
     DEFAULT_BLOCK_N,
     splitk_gemm,
 )
+
+
+_DISPATCH: ContextVar[Counter | None] = ContextVar("dispatch", default=None)
+
+
+@contextlib.contextmanager
+def count_dispatch():
+    """Yield a Counter of ``(op, path)`` -> operands traced inside the block,
+    where path is ``"kernel"`` or ``"jnp"`` (the oracle fallback)."""
+    counts: Counter[tuple[str, str]] = Counter()
+    token = _DISPATCH.set(counts)
+    try:
+        yield counts
+    finally:
+        _DISPATCH.reset(token)
+
+
+def _note(op: str, path: str) -> None:
+    counts = _DISPATCH.get()
+    if counts is not None:
+        counts[op, path] += 1
 
 
 def _interpret_default() -> bool:
@@ -89,7 +121,9 @@ def tiered_matmul(
     # Degenerate tiers (fully local / fully remote operand) take the oracle:
     # the kernel grid assumes both partitions are non-empty.
     if not use_kernel or not aligned or n_loc == 0 or n_rem == 0:
+        _note("gemm", "jnp")
         return ref.splitk_gemm_ref(x.reshape(-1, k), wl, wr).reshape(*lead, n_loc + n_rem)
+    _note("gemm", "kernel")
 
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
@@ -126,7 +160,9 @@ def tiered_decode_attention(
         if tuned is not None:
             block_s = tuned["block_s"]
     if not use_kernel or s % block_s or kr.shape[0] == 0 and kl.shape[0] == 0:
+        _note("attn", "jnp")
         return ref.splitk_flashattn_ref(q, kl, vl, kr, vr, kv_len)
+    _note("attn", "kernel")
     return splitk_flashattn(
         q, kl, vl, kr, vr, kv_len=kv_len, block_s=block_s, window=window,
         interpret=_interpret_default() if interpret is None else interpret)
@@ -163,8 +199,10 @@ def paged_decode_attention(
         if tuned is not None:
             window = max(1, min(window, tuned["slots"]))
     if not use_kernel:
+        _note("paged_attn", "jnp")
         return ref.paged_flashattn_ref(q, kl, vl, kr, vr, table, tier, lens,
                                        scale=scale)
+    _note("paged_attn", "kernel")
     return paged_splitk_flashattn(
         q, kl, vl, kr, vr, table, tier, lens, window=window, scale=scale,
         interpret=_interpret_default() if interpret is None else interpret)
@@ -199,7 +237,6 @@ def mesh_fetch_params(params, mesh, axis_name: str):
     the single-chip decode/prefill paths consume unchanged.  Trees with no
     sharded leaf (offload 0, or no mesh) are returned as-is.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     leaves, treedef = jax.tree_util.tree_flatten(
@@ -228,11 +265,11 @@ def mesh_fetch_params(params, mesh, axis_name: str):
                 TieredArray(stub, r, axis=axes[k]), axis_name).remote
         return out
 
-    gathered = shard_map(
+    gathered = jax.shard_map(
         fetch, mesh=mesh,
         in_specs=({str(i): shard_spec(leaves[i]) for i in idx},),
         out_specs={k: P() for k in remotes},
-        check_rep=False,
+        check_vma=False,
     )(remotes)
     for i in idx:
         leaf = leaves[i]

@@ -1,11 +1,13 @@
 """SplitK_FlashAttn — direct-access tiered flash-decode attention (paper §5).
 
 Decode attention for a batch of requests whose KV caches are partitioned
-along the *batch* dimension between the local tier (HBM) and the remote tier
-(host DRAM) — exactly the paper's `SplitK_FlashAttn` partitioning.  Each
-grid step handles one request; requests homed on the host tier stream their
-K/V chunks directly from ``pltpu.HOST`` into VMEM (never staging through
-HBM), with the in-flight chunk count bounded by the congestion ``window``.
+along the *batch* dimension between a local and a remote tier — exactly the
+paper's `SplitK_FlashAttn` partitioning.  Each grid step handles one
+request; requests homed on the remote tier stream their K/V chunks from the
+remote buffer into VMEM, with the in-flight chunk count bounded by the
+congestion ``window``.  Both tiers are ``pl.ANY`` operands resident in HBM:
+a ``pltpu.HOST`` operand does not compile on v5e with this toolchain (see
+`kernels.splitk_gemm`).
 The sequence dimension is processed split-K style with an online-softmax
 accumulator, so arbitrarily long caches run in O(block_s) VMEM.
 
@@ -23,7 +25,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 DEFAULT_BLOCK_S = 256
 DEFAULT_WINDOW = 2
@@ -203,8 +204,8 @@ def splitk_flashattn(
             pl.BlockSpec((1, h, hd), lambda i, order: (order[i], 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=compat.HOST),
-            pl.BlockSpec(memory_space=compat.HOST),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, h, hd), lambda i, order: (order[i], 0, 0)),
         scratch_shapes=[
@@ -222,7 +223,7 @@ def splitk_flashattn(
             _kernel, block_s=block_s, n_loc=b_loc, kv_len=kv_len, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -361,8 +362,8 @@ def paged_splitk_flashattn(
             pl.BlockSpec((1, h, hd), lambda i, order, table, tier, lens: (order[i], 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=compat.HOST),
-            pl.BlockSpec(memory_space=compat.HOST),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, h, hd), lambda i, order, table, tier, lens: (order[i], 0, 0)),
         scratch_shapes=[
@@ -379,7 +380,7 @@ def paged_splitk_flashattn(
         functools.partial(_paged_kernel, window=window, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

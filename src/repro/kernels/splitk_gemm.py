@@ -1,12 +1,19 @@
 """SplitK_GEMM — direct-access tiered GEMM (paper §4.1, Fig. 5) on TPU.
 
 Computes ``y = x @ concat(w_local, w_remote, axis=1)`` where the weight is
-column-partitioned between the local tier (HBM, ``pl.ANY``) and the remote
-tier (host DRAM, ``pltpu.HOST``).  Neither partition is staged through the
-other tier: every output tile's producer stream DMAs its weight tiles
-*directly* from its home tier into VMEM scratch (the TPU analogue of the
-paper's TMA remote→SMEM path), double/multi-buffered so compute on chunk k
-overlaps the DMA of chunk k+window.
+column-partitioned between a local and a remote tier.  Every output tile's
+producer stream DMAs its weight tiles from its home tier's buffer into VMEM
+scratch (the TPU analogue of the paper's TMA remote→SMEM path),
+double/multi-buffered so compute on chunk k overlaps the DMA of chunk
+k+window.
+
+Both operands are declared ``pl.ANY`` and both tiers live in HBM.  With
+JAX 0.9 / libtpu 0.0.34 on v5e, a ``pltpu.HOST`` operand does not compile:
+resident in HBM it aborts the compiler ("Unsupported operand memory
+space"), and ``pinned_host`` fails as an unimplemented host→VMEM DMA.  A
+kernel that copies a ``pinned_host`` array into an HBM buffer does
+compile, so host residency has to be a two-hop host→HBM→VMEM stream; until
+then the remote partition is a separate HBM buffer with the same tiling.
 
 Paper mechanism ↔ kernel knob:
   * per-op offload ratio      → width of ``w_remote`` (set by the planner,
@@ -26,7 +33,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 128
@@ -38,7 +44,7 @@ def _kernel(
     order_ref,                 # scalar prefetch: grid step -> n-tile id
     x_ref,                     # [bm, K] VMEM
     wl_hbm,                    # [K, N_loc] local tier (ANY/HBM)
-    wr_host,                   # [K, N_rem] remote tier (HOST)
+    wr_host,                   # [K, N_rem] remote tier (ANY/HBM)
     o_ref,                     # [bm, bn] VMEM
     w_vmem,                    # scratch [slots, bk, bn]
     acc_ref,                   # scratch [bm, bn] fp32
@@ -167,7 +173,7 @@ def splitk_gemm(
         in_specs=[
             pl.BlockSpec((block_m, k), lambda i, j, order: (i, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=compat.HOST),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda i, j, order: (i, order[j])),
@@ -183,7 +189,7 @@ def splitk_gemm(
             n_loc_tiles=n_loc_tiles, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n_loc + n_rem), x.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

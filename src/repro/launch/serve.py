@@ -35,12 +35,16 @@ import json
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as C
+from repro.configs.base import ModelConfig
+from repro.core.hardware import TPU_V5E, HardwareSpec, hardware_for
 from repro.frontend.metrics import ModeledClock
 from repro.frontend.scheduler import scheduler_names
 from repro.frontend.workload import Trace, poisson_trace
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import BENCH_SCHEMA_VERSION, provenance, serving_registry
@@ -92,7 +96,7 @@ def bench_report(args, engine: ServingEngine, stats, wall: float,
     return report
 
 
-def main(argv: list[str] | None = None) -> dict:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2_7b")
     ap.add_argument("--smoke", action="store_true")
@@ -197,7 +201,63 @@ def main(argv: list[str] | None = None) -> dict:
                          "(e.g. 6:0.3).  The engine must degrade — demote, "
                          "re-plan to a higher offload ratio, shed admissions "
                          "— and finish with zero failed requests")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def resolve_hw() -> HardwareSpec:
+    """The spec the planner prices.  On a TPU backend it is the attached
+    chip's, looked up by device kind (an unknown kind is an error); on any
+    other backend the kernels run interpreted and the planner prices a v5e."""
+    if jax.default_backend() == "tpu":
+        return hardware_for(jax.devices()[0])
+    return TPU_V5E
+
+
+def build_engine(args: argparse.Namespace, **engine_kw) -> tuple[ModelConfig, ServingEngine]:
+    """The config and the serving engine that ``args`` describe.
+
+    Parameters are created in ``cfg.dtype`` by one compiled program (eager
+    init compiles every random op per leaf shape, and holds each leaf's
+    unscaled draw next to the scaled one) and handed to the engine without
+    a name here: once the engine has partitioned them into tiers, nothing
+    holds the unsplit tree.  ``engine_kw`` passes the observability and
+    clock hooks through to `ServingEngine`."""
+    cfg = C.get_smoke(args.arch) if args.smoke else C.get(args.arch)
+    mesh = None
+    if args.mesh_devices > 1:
+        if jax.device_count() < args.mesh_devices:
+            raise SystemExit(
+                f"--mesh-devices {args.mesh_devices} needs that many devices "
+                f"(have {jax.device_count()}); on CPU set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={args.mesh_devices}")
+        mesh = jax.sharding.Mesh(
+            np.array(jax.devices()[:args.mesh_devices]), ("model",))
+    engine = ServingEngine(
+        cfg, jax.jit(M.init_params, static_argnums=(0, 2))(
+            cfg, jax.random.PRNGKey(0), jnp.dtype(cfg.dtype)),
+        hw=resolve_hw(), max_batch=args.max_batch, max_len=args.max_len,
+        hbm_budget_bytes=args.hbm_gb * 1e9 if args.hbm_gb is not None else None,
+        global_offload_ratio=None if args.hbm_gb is not None else args.offload_ratio,
+        use_kernels=not args.no_kernels, page_size=args.page_size,
+        adaptive=args.adaptive, mesh=mesh,
+        scheduler=args.scheduler, prefill_chunk=args.prefill_chunk,
+        check_invariants=args.check_invariants,
+        jit_step=not args.no_jit, **engine_kw)
+    return cfg, engine
+
+
+def peak_device_bytes() -> int | None:
+    """Largest ``peak_bytes_in_use`` over the devices, where the backend
+    reports memory statistics (TPU does; the CPU backend does not)."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.devices()]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
+
+
+def main(argv: list[str] | None = None) -> dict:
+    args = parse_args(argv)
+    compile_cache.configure()
     shrink = None
     if args.hbm_shrink:
         try:
@@ -210,17 +270,6 @@ def main(argv: list[str] | None = None) -> dict:
     if args.bench_json is None and args.adaptive:
         args.bench_json = "BENCH_serving.json"
 
-    cfg = C.get_smoke(args.arch) if args.smoke else C.get(args.arch)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    mesh = None
-    if args.mesh_devices > 1:
-        if jax.device_count() < args.mesh_devices:
-            raise SystemExit(
-                f"--mesh-devices {args.mesh_devices} needs that many devices "
-                f"(have {jax.device_count()}); on CPU set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={args.mesh_devices}")
-        mesh = jax.sharding.Mesh(
-            np.array(jax.devices()[:args.mesh_devices]), ("model",))
     trace = None
     if args.trace:
         trace = Trace.load(args.trace)
@@ -263,17 +312,9 @@ def main(argv: list[str] | None = None) -> dict:
     if args.attribution:
         from repro.obs.attribution import AttributionProfiler
         profiler = AttributionProfiler()
-    engine = ServingEngine(
-        cfg, params, max_batch=args.max_batch, max_len=args.max_len,
-        hbm_budget_bytes=args.hbm_gb * 1e9 if args.hbm_gb is not None else None,
-        global_offload_ratio=None if args.hbm_gb is not None else args.offload_ratio,
-        use_kernels=not args.no_kernels, page_size=args.page_size,
-        adaptive=args.adaptive, mesh=mesh,
-        scheduler=args.scheduler, prefill_chunk=args.prefill_chunk,
-        clock=ModeledClock() if trace is not None else None,
-        check_invariants=args.check_invariants,
-        recorder=recorder, flight=flight,
-        jit_step=not args.no_jit, tuner=tuner, profiler=profiler)
+    cfg, engine = build_engine(
+        args, clock=ModeledClock() if trace is not None else None,
+        recorder=recorder, flight=flight, tuner=tuner, profiler=profiler)
     if shrink is not None:
         engine.schedule_hbm_shrink(*shrink)
         print(f"chaos: HBM shrink to {shrink[1]:.0%} of the local pool "
@@ -334,6 +375,9 @@ def main(argv: list[str] | None = None) -> dict:
           f"queue p95 {stats.queue_delay_p95*1e3:.1f} ms | "
           f"e2e p95 {stats.e2e_p95*1e3:.1f} ms | "
           f"prefill {stats.prefill_time:.2f}s")
+    peak = peak_device_bytes()
+    if peak is not None:
+        print(f"peak device memory {peak / 1e9:.3f} GB")
     if stats.prefill_chunks or stats.preemptions:
         print(f"frontend: prefill chunks {stats.prefill_chunks} | "
               f"preemptions {stats.preemptions} "

@@ -13,7 +13,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.compat import get_abstract_mesh
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import model as M
 from repro.optim import adamw
@@ -31,7 +30,7 @@ def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
 def constrain_tree(tree, spec_tree):
     """with_sharding_constraint over a tree of PartitionSpecs; no-op when no
     abstract mesh is active (plain-CPU tests/drivers)."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if spec_tree is None or mesh is None or not mesh.axis_names:
         return tree
     return jax.tree.map(
@@ -184,7 +183,6 @@ def make_dp_train_step_compressed(
     opt_cfg: adamw.AdamWConfig = adamw.AdamWConfig(),
     axis: str = "data",
 ) -> Callable:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import collectives
@@ -214,8 +212,8 @@ def make_dp_train_step_compressed(
         out_specs = (P(), jax.tree.map(lambda _: P(), params),
                      jax.tree.map(lambda _: P(), opt_state),
                      jax.tree.map(lambda _: P(), residual), P())
-        return shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(
+        return jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(
             params, opt_state, residual, batch)
 
     return step

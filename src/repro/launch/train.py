@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import tempfile
 import time
 
 import jax
@@ -25,7 +27,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import SyntheticPipeline
 from repro.distributed.fault import FaultInjector, RestartLoop, StragglerDetector
-from repro.launch import sharding, steps as S
+from repro.launch import compile_cache, sharding, steps as S
 from repro.launch.mesh import make_dev_mesh
 from repro.models import model as M
 from repro.optim import adamw
@@ -48,10 +50,13 @@ def main(argv: list[str] | None = None) -> dict:
     ap.add_argument("--dtype", default="float32")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    compile_cache.configure()
 
     cfg = C.get_smoke(args.arch) if args.smoke else C.get(args.arch)
     if not args.ckpt_dir:
-        args.ckpt_dir = f"/tmp/repro_ckpt_{args.arch}{'_smoke' if args.smoke else ''}"
+        args.ckpt_dir = os.path.join(
+            tempfile.gettempdir(),
+            f"repro_ckpt_{args.arch}{'_smoke' if args.smoke else ''}")
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
     mesh = make_dev_mesh(len(jax.devices()), 1)

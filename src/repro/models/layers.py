@@ -13,7 +13,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.compat import get_abstract_mesh
 from repro.configs.base import ModelConfig
 from repro.core.tiering import TieredArray, matmul
 
@@ -36,10 +35,11 @@ Matmul = Any
 # --------------------------------------------------------------------------
 def hint(x: jax.Array, *spec: str | None) -> jax.Array:
     """spec entries: 'batch' | 'model' | None per dimension."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
-    names = set(mesh.axis_names)
+    # Inside a shard_map the mapped axes are manual: nothing to constrain.
+    names = set(mesh.axis_names) - set(mesh.manual_axes)
     batch_axes = tuple(a for a in ("pod", "data") if a in names) or None
     resolved: list[Any] = []
     for dim, s in zip(x.shape, spec, strict=True):

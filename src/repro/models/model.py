@@ -29,88 +29,89 @@ _INIT_STD = 0.02
 # ==========================================================================
 # Init
 # ==========================================================================
-def _norm_params(cfg: ModelConfig, lead: tuple[int, ...], prefix: str, d: int) -> Params:
-    p = {f"{prefix}_w": jnp.ones(lead + (d,))}
+def _norm_params(cfg: ModelConfig, lead: tuple[int, ...], prefix: str, d: int,
+                 dtype) -> Params:
+    p = {f"{prefix}_w": jnp.ones(lead + (d,), dtype)}
     if cfg.norm == "layernorm":
-        p[f"{prefix}_b"] = jnp.zeros(lead + (d,))
+        p[f"{prefix}_b"] = jnp.zeros(lead + (d,), dtype)
     return p
 
 
-def _dense(key, lead, shape, std=_INIT_STD):
-    return jax.random.normal(key, lead + shape) * std
+def _dense(key, lead, shape, dtype, std=_INIT_STD):
+    return jax.random.normal(key, lead + shape, dtype) * std
 
 
-def _attn_params(cfg: ModelConfig, key, lead: tuple[int, ...]) -> Params:
+def _attn_params(cfg: ModelConfig, key, lead: tuple[int, ...], dtype) -> Params:
     hd = cfg.resolved_head_dim
     hp = cfg.padded_heads
     keys = jax.random.split(key, 4)
-    wq = _dense(keys[0], lead, (cfg.d_model, hp * hd))
-    wo = _dense(keys[1], lead, (hp * hd, cfg.d_model))
+    wq = _dense(keys[0], lead, (cfg.d_model, hp * hd), dtype)
+    wo = _dense(keys[1], lead, (hp * hd, cfg.d_model), dtype)
     if hp > cfg.n_heads:
         # TP head padding: zero weights beyond n_heads — numerically exact.
         wq = wq.at[..., cfg.n_heads * hd:].set(0.0)
         wo = wo.at[..., cfg.n_heads * hd:, :].set(0.0)
     p: Params = {
         "wq": wq,
-        "wkv": _dense(keys[2], lead, (cfg.d_model, 2 * cfg.n_kv_heads * hd)),
+        "wkv": _dense(keys[2], lead, (cfg.d_model, 2 * cfg.n_kv_heads * hd), dtype),
         "wo": wo,
     }
     if cfg.qkv_bias:
-        p["bq"] = jnp.zeros(lead + (hp * hd,))
-        p["bkv"] = jnp.zeros(lead + (2 * cfg.n_kv_heads * hd,))
+        p["bq"] = jnp.zeros(lead + (hp * hd,), dtype)
+        p["bkv"] = jnp.zeros(lead + (2 * cfg.n_kv_heads * hd,), dtype)
     if cfg.qk_norm:
-        p["q_norm_w"] = jnp.ones(lead + (hd,))
-        p["k_norm_w"] = jnp.ones(lead + (hd,))
+        p["q_norm_w"] = jnp.ones(lead + (hd,), dtype)
+        p["k_norm_w"] = jnp.ones(lead + (hd,), dtype)
     return p
 
 
-def _mla_params(cfg: ModelConfig, key, lead: tuple[int, ...]) -> Params:
+def _mla_params(cfg: ModelConfig, key, lead: tuple[int, ...], dtype) -> Params:
     keys = jax.random.split(key, 5)
     h, nd, rd, vd = cfg.n_heads, cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
     p: Params = {
-        "wkv_a": _dense(keys[0], lead, (cfg.d_model, cfg.kv_lora_rank + rd)),
-        "kv_a_norm_w": jnp.ones(lead + (cfg.kv_lora_rank,)),
-        "wkv_b": _dense(keys[1], lead, (cfg.kv_lora_rank, h * (nd + vd))),
-        "wo": _dense(keys[2], lead, (h * vd, cfg.d_model)),
+        "wkv_a": _dense(keys[0], lead, (cfg.d_model, cfg.kv_lora_rank + rd), dtype),
+        "kv_a_norm_w": jnp.ones(lead + (cfg.kv_lora_rank,), dtype),
+        "wkv_b": _dense(keys[1], lead, (cfg.kv_lora_rank, h * (nd + vd)), dtype),
+        "wo": _dense(keys[2], lead, (h * vd, cfg.d_model), dtype),
     }
     if cfg.q_lora_rank:
-        p["wq_a"] = _dense(keys[3], lead, (cfg.d_model, cfg.q_lora_rank))
-        p["q_a_norm_w"] = jnp.ones(lead + (cfg.q_lora_rank,))
-        p["wq_b"] = _dense(keys[4], lead, (cfg.q_lora_rank, h * (nd + rd)))
+        p["wq_a"] = _dense(keys[3], lead, (cfg.d_model, cfg.q_lora_rank), dtype)
+        p["q_a_norm_w"] = jnp.ones(lead + (cfg.q_lora_rank,), dtype)
+        p["wq_b"] = _dense(keys[4], lead, (cfg.q_lora_rank, h * (nd + rd)), dtype)
     else:
-        p["wq_b"] = _dense(keys[4], lead, (cfg.d_model, h * (nd + rd)))
+        p["wq_b"] = _dense(keys[4], lead, (cfg.d_model, h * (nd + rd)), dtype)
     return p
 
 
-def _mlp_params(cfg: ModelConfig, key, lead: tuple[int, ...]) -> Params:
+def _mlp_params(cfg: ModelConfig, key, lead: tuple[int, ...], dtype) -> Params:
     keys = jax.random.split(key, 2)
     mult = 2 if cfg.mlp == "swiglu" else 1
     p: Params = {
-        "wi": _dense(keys[0], lead, (cfg.d_model, mult * cfg.d_ff)),
-        "wdown": _dense(keys[1], lead, (cfg.d_ff, cfg.d_model)),
+        "wi": _dense(keys[0], lead, (cfg.d_model, mult * cfg.d_ff), dtype),
+        "wdown": _dense(keys[1], lead, (cfg.d_ff, cfg.d_model), dtype),
     }
     if cfg.norm == "layernorm":       # bias-ful families (OPT/starcoder/hubert)
-        p["bi"] = jnp.zeros(lead + (mult * cfg.d_ff,))
-        p["bdown"] = jnp.zeros(lead + (cfg.d_model,))
+        p["bi"] = jnp.zeros(lead + (mult * cfg.d_ff,), dtype)
+        p["bdown"] = jnp.zeros(lead + (cfg.d_model,), dtype)
     return p
 
 
-def _moe_params(cfg: ModelConfig, key, lead: tuple[int, ...]) -> Params:
+def _moe_params(cfg: ModelConfig, key, lead: tuple[int, ...], dtype) -> Params:
     keys = jax.random.split(key, 5)
     e, ff = cfg.n_experts, cfg.moe_d_ff
     p: Params = {
-        "router": _dense(keys[0], lead, (cfg.d_model, e)),
-        "experts_wi": _dense(keys[1], lead, (e, cfg.d_model, 2 * ff)),
-        "experts_wdown": _dense(keys[2], lead, (e, ff, cfg.d_model)),
+        "router": _dense(keys[0], lead, (cfg.d_model, e), dtype),
+        "experts_wi": _dense(keys[1], lead, (e, cfg.d_model, 2 * ff), dtype),
+        "experts_wdown": _dense(keys[2], lead, (e, ff, cfg.d_model), dtype),
     }
     if cfg.n_shared_experts:
         sf = ff * cfg.n_shared_experts
-        p["shared_wi"] = _dense(keys[3], lead, (cfg.d_model, 2 * sf))
-        p["shared_wdown"] = _dense(keys[4], lead, (sf, cfg.d_model))
+        p["shared_wi"] = _dense(keys[3], lead, (cfg.d_model, 2 * sf), dtype)
+        p["shared_wdown"] = _dense(keys[4], lead, (sf, cfg.d_model), dtype)
     return p
 
 
-def _ssm_params(cfg: ModelConfig, key, lead: tuple[int, ...]) -> Params:
+def _ssm_params(cfg: ModelConfig, key, lead: tuple[int, ...], dtype) -> Params:
     keys = jax.random.split(key, 3)
     d_inner = cfg.ssm_expand * cfg.d_model
     nh = d_inner // cfg.ssm_head_dim
@@ -118,60 +119,60 @@ def _ssm_params(cfg: ModelConfig, key, lead: tuple[int, ...]) -> Params:
     kz, kx, kbc, kdt = jax.random.split(keys[0], 4)
     return {
         # split projections (sharding-aligned — perf iteration A2)
-        "z_proj": _dense(kz, lead, (cfg.d_model, d_inner)),
-        "x_proj": _dense(kx, lead, (cfg.d_model, d_inner)),
-        "bc_proj": _dense(kbc, lead, (cfg.d_model, 2 * cfg.ssm_n_groups * cfg.ssm_state)),
-        "dt_proj": _dense(kdt, lead, (cfg.d_model, nh)),
-        "conv_w": _dense(keys[1], lead, (cfg.ssm_conv_width, conv_dim), std=0.1),
-        "dt_bias": jnp.zeros(lead + (nh,)),
-        "A_log": jnp.zeros(lead + (nh,)),         # A = -exp(0) = -1
-        "D": jnp.ones(lead + (nh,)),
-        "ssm_norm_w": jnp.ones(lead + (d_inner,)),
-        "ssm_out": _dense(keys[2], lead, (d_inner, cfg.d_model)),
+        "z_proj": _dense(kz, lead, (cfg.d_model, d_inner), dtype),
+        "x_proj": _dense(kx, lead, (cfg.d_model, d_inner), dtype),
+        "bc_proj": _dense(kbc, lead, (cfg.d_model, 2 * cfg.ssm_n_groups * cfg.ssm_state), dtype),
+        "dt_proj": _dense(kdt, lead, (cfg.d_model, nh), dtype),
+        "conv_w": _dense(keys[1], lead, (cfg.ssm_conv_width, conv_dim), dtype, std=0.1),
+        "dt_bias": jnp.zeros(lead + (nh,), dtype),
+        "A_log": jnp.zeros(lead + (nh,), dtype),         # A = -exp(0) = -1
+        "D": jnp.ones(lead + (nh,), dtype),
+        "ssm_norm_w": jnp.ones(lead + (d_inner,), dtype),
+        "ssm_out": _dense(keys[2], lead, (d_inner, cfg.d_model), dtype),
     }
 
 
-def _layer_params(cfg: ModelConfig, key, lead: tuple[int, ...]) -> Params:
+def _layer_params(cfg: ModelConfig, key, lead: tuple[int, ...], dtype) -> Params:
     keys = jax.random.split(key, 3)
     p: Params = {}
     if cfg.family in ("ssm",) or (cfg.family == "hybrid"):
-        p.update(_norm_params(cfg, lead, "ln1", cfg.d_model))
-        p.update(_ssm_params(cfg, keys[0], lead))
+        p.update(_norm_params(cfg, lead, "ln1", cfg.d_model, dtype))
+        p.update(_ssm_params(cfg, keys[0], lead, dtype))
         return p
-    p.update(_norm_params(cfg, lead, "ln1", cfg.d_model))
-    p.update(_mla_params(cfg, keys[0], lead) if cfg.use_mla else _attn_params(cfg, keys[0], lead))
-    p.update(_norm_params(cfg, lead, "ln2", cfg.d_model))
-    p.update(_moe_params(cfg, keys[1], lead) if cfg.family == "moe" else _mlp_params(cfg, keys[1], lead))
+    p.update(_norm_params(cfg, lead, "ln1", cfg.d_model, dtype))
+    p.update(_mla_params(cfg, keys[0], lead, dtype) if cfg.use_mla else _attn_params(cfg, keys[0], lead, dtype))
+    p.update(_norm_params(cfg, lead, "ln2", cfg.d_model, dtype))
+    p.update(_moe_params(cfg, keys[1], lead, dtype) if cfg.family == "moe" else _mlp_params(cfg, keys[1], lead, dtype))
     return p
 
 
-def _shared_block_params(cfg: ModelConfig, key, lead: tuple[int, ...]) -> Params:
+def _shared_block_params(cfg: ModelConfig, key, lead: tuple[int, ...], dtype) -> Params:
     """Zamba2 shared attention+MLP block (input: concat(h, h0) -> d)."""
     keys = jax.random.split(key, 3)
-    p: Params = {"concat_proj": _dense(keys[0], lead, (2 * cfg.d_model, cfg.d_model))}
-    p.update(_norm_params(cfg, lead, "ln1", cfg.d_model))
-    p.update(_attn_params(cfg, keys[1], lead))
-    p.update(_norm_params(cfg, lead, "ln2", cfg.d_model))
-    p.update(_mlp_params(cfg, keys[2], lead))
+    p: Params = {"concat_proj": _dense(keys[0], lead, (2 * cfg.d_model, cfg.d_model), dtype)}
+    p.update(_norm_params(cfg, lead, "ln1", cfg.d_model, dtype))
+    p.update(_attn_params(cfg, keys[1], lead, dtype))
+    p.update(_norm_params(cfg, lead, "ln2", cfg.d_model, dtype))
+    p.update(_mlp_params(cfg, keys[2], lead, dtype))
     return p
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
     keys = jax.random.split(key, 6)
     lead = (cfg.n_layers,)
-    p: Params = {"layers": _layer_params(cfg, keys[0], lead)}
+    p: Params = {"layers": _layer_params(cfg, keys[0], lead, dtype)}
     if cfg.family == "encoder":
-        p["in_proj"] = _dense(keys[1], (), (AUDIO_FRAME_DIM, cfg.d_model))
+        p["in_proj"] = _dense(keys[1], (), (AUDIO_FRAME_DIM, cfg.d_model), dtype)
     else:
-        p["embed"] = _dense(keys[1], (), (cfg.vocab, cfg.d_model))
+        p["embed"] = _dense(keys[1], (), (cfg.vocab, cfg.d_model), dtype)
     if cfg.family == "vlm":
-        p["vision_proj"] = _dense(keys[2], (), (VISION_EMBED_DIM, cfg.d_model))
+        p["vision_proj"] = _dense(keys[2], (), (VISION_EMBED_DIM, cfg.d_model), dtype)
     if cfg.family == "hybrid" and cfg.hybrid_shared_blocks:
-        p["shared"] = _shared_block_params(cfg, keys[3], (cfg.hybrid_shared_blocks,))
-    p.update(_norm_params(cfg, (), "final", cfg.d_model))
+        p["shared"] = _shared_block_params(cfg, keys[3], (cfg.hybrid_shared_blocks,), dtype)
+    p.update(_norm_params(cfg, (), "final", cfg.d_model, dtype))
     if not cfg.tie_embeddings:
-        p["lm_head"] = _dense(keys[4], (), (cfg.d_model, cfg.vocab))
-    return jax.tree.map(lambda a: a.astype(dtype), p)
+        p["lm_head"] = _dense(keys[4], (), (cfg.d_model, cfg.vocab), dtype)
+    return p
 
 
 # ==========================================================================

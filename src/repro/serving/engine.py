@@ -335,10 +335,14 @@ class ServingEngine:
         # One partition pass for every family (the unified API); at ratio 0
         # no leaf is wrapped and the kernel path runs over plain weights.
         self.tiered = self.use_kernels
+        dtype = next(iter(jax.tree.leaves(params))).dtype
         if self.tiered:
             self.params = self.plan.partition(params, align=self._align)
         else:
             self.params = params
+        # The engine keeps only the partitioned tree: when the caller holds
+        # no other reference, the unsplit weights are freed here.
+        del params
         if mesh is not None:
             # Commit the tree to the serving mesh: remote partitions as
             # disjoint 1/P host-link slices, everything else replicated.
@@ -355,7 +359,6 @@ class ServingEngine:
         self._weight_bytes = weight_tier_bytes(self.params)
         self._weight_link_bytes = weight_link_bytes(self.params, self.n_links)
 
-        dtype = next(iter(jax.tree.leaves(params))).dtype
         self.pcache: PagedTieredCache | None = None
         self.cache: dict[str, jax.Array] | None = None
         if self.tiered and cfg.family in ("dense", "vlm", "moe"):
@@ -1055,6 +1058,11 @@ class ServingEngine:
         self._elastic_step()               # drain any local-budget deficit
         t_admit0 = self.clock.now() if self.recorder.enabled else 0.0
         prefill_tokens = self._admit()
+        if self._jit:
+            # The compiled step gathers the sharded partitions itself: free
+            # the prefills' gathered copy (a whole remote tier per device
+            # under a mesh) before it runs.
+            self._step_params = None
         if self.recorder.enabled:
             self.recorder.span(ENGINE, 0, "admission", t_admit0,
                                self.clock.now(), cat="sched",

@@ -138,6 +138,21 @@ def fetch_remote_shards(params: dict[str, Any], mesh: Any,
         params, mesh, mesh_axis or mesh.axis_names[-1])
 
 
+def on_every_device(fn: Callable, mesh: Any) -> Callable:
+    """Run ``fn`` whole on every device of ``mesh`` (identity off-mesh).
+
+    XLA cannot partition a Mosaic kernel, so under a serving mesh the decode
+    body runs inside a ``shard_map`` whose operands and results are all
+    replicated: each chip computes the full batch on the full operands the
+    fetch-once broadcast rebuilt, as the single-chip path does."""
+    if mesh is None:
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
 # --------------------------------------------------------------------------
 # Attention bodies.  The cache layouts differ only in how the new K/V row is
 # written and how attention gathers the cache, so every decode step injects
@@ -357,15 +372,21 @@ def paged_tiered_decode_step(
     slots must be pointed at a sink page by the caller.  With a ``mesh``
     the weights' sharded host partitions are rebuilt first through the
     fetch-once broadcast (:func:`fetch_remote_shards`)."""
+    def body(params, pools, tokens, positions, attn_lens, table, tier,
+             wr_tier, wr_idx, wr_off):
+        pools = dict(pools)
+        write_and_attend = _paged_writer(
+            pools, table, tier, attn_lens, wr_tier, wr_idx, wr_off,
+            sink_local, sink_remote, window, use_kernel, tuner)
+        logits = _decode_transformer(
+            cfg, params, tokens, positions, window, use_kernel,
+            write_and_attend, tuner)
+        return logits, pools
+
     params = fetch_remote_shards(params, mesh, mesh_axis)
-    pools = dict(pools)
-    write_and_attend = _paged_writer(
-        pools, table, tier, attn_lens, wr_tier, wr_idx, wr_off,
-        sink_local, sink_remote, window, use_kernel, tuner)
-    logits = _decode_transformer(
-        cfg, params, tokens, positions, window, use_kernel, write_and_attend,
-        tuner)
-    return logits, pools
+    return on_every_device(body, mesh)(
+        params, pools, tokens, positions, attn_lens, table, tier,
+        wr_tier, wr_idx, wr_off)
 
 
 def tiered_ssm_decode_step(
@@ -385,23 +406,25 @@ def tiered_ssm_decode_step(
     No KV cache — the conv window and SSD state are per-slot recurrent
     state, always HBM-resident; the offloaded operands are the projection
     stacks (``ssm_in`` / ``ssm_out``), computed by the tiered GEMM."""
-    params = fetch_remote_shards(params, mesh, mesh_axis)
-    x = params["embed"][tokens]
-
     def kmm(a, w):
         return _mm(a, w, window, use_kernel, tuner)
 
-    convs, states = [], []
-    for i in range(cfg.n_layers):
-        lp = layer_slice(params["layers"], i)
-        hn = L.norm(cfg, x, lp, "ln1")
-        y, conv_i, state_i = S.ssm_block_decode(
-            cfg, hn, lp, cache["conv"][i], cache["state"][i], mm=kmm)
-        x = x + y
-        convs.append(conv_i)
-        states.append(state_i)
-    logits = _head(cfg, params, x, window, use_kernel, tuner)
-    return logits, {"conv": jnp.stack(convs), "state": jnp.stack(states)}
+    def body(params, cache, tokens):
+        x = params["embed"][tokens]
+        convs, states = [], []
+        for i in range(cfg.n_layers):
+            lp = layer_slice(params["layers"], i)
+            hn = L.norm(cfg, x, lp, "ln1")
+            y, conv_i, state_i = S.ssm_block_decode(
+                cfg, hn, lp, cache["conv"][i], cache["state"][i], mm=kmm)
+            x = x + y
+            convs.append(conv_i)
+            states.append(state_i)
+        logits = _head(cfg, params, x, window, use_kernel, tuner)
+        return logits, {"conv": jnp.stack(convs), "state": jnp.stack(states)}
+
+    params = fetch_remote_shards(params, mesh, mesh_axis)
+    return on_every_device(body, mesh)(params, cache, tokens)
 
 
 def tiered_hybrid_decode_step(
@@ -429,41 +452,47 @@ def tiered_hybrid_decode_step(
     """One ragged decode step for Zamba2-style hybrids: each group runs its
     shared attention+MLP block (GQA over the group's paged tiered KV layer)
     followed by ``hybrid_attn_every`` tiered SSM layers."""
-    params = fetch_remote_shards(params, mesh, mesh_axis)
-    pools = dict(pools)
-    write_and_attend = _paged_writer(
-        pools, table, tier, attn_lens, wr_tier, wr_idx, wr_off,
-        sink_local, sink_remote, window, use_kernel, tuner)
-
     def kmm(a, w):
         return _mm(a, w, window, use_kernel, tuner)
 
-    x = params["embed"][tokens]
-    h0 = x
-    k_every = cfg.hybrid_attn_every
-    n_groups = cfg.n_layers // k_every
-    n_blocks = max(1, cfg.hybrid_shared_blocks)
-    convs, states = [], []
-    for g_idx in range(n_groups):
-        sp = layer_slice(params["shared"], g_idx % n_blocks)
-        z = jnp.concatenate([x, h0], axis=-1) @ sp["concat_proj"]
-        zn = L.norm(cfg, z, sp, "ln1")
-        attn = _gqa_attend(cfg, sp, zn, positions, g_idx, window, use_kernel,
-                           write_and_attend, tuner)
-        z = z + _mm(attn, sp["wo"], window, use_kernel, tuner)
-        z = z + L.mlp_block(cfg, L.norm(cfg, z, sp, "ln2"), sp, mm=kmm)
-        x = x + z
-        for j in range(k_every):
-            li = g_idx * k_every + j
-            lp = layer_slice(params["layers"], li)
-            hn = L.norm(cfg, x, lp, "ln1")
-            y, conv_i, state_i = S.ssm_block_decode(
-                cfg, hn, lp, cache["conv"][li], cache["state"][li], mm=kmm)
-            x = x + y
-            convs.append(conv_i)
-            states.append(state_i)
-    logits = _head(cfg, params, x, window, use_kernel, tuner)
-    return logits, {"conv": jnp.stack(convs), "state": jnp.stack(states)}, pools
+    def body(params, cache, pools, tokens, positions, attn_lens, table, tier,
+             wr_tier, wr_idx, wr_off):
+        pools = dict(pools)
+        write_and_attend = _paged_writer(
+            pools, table, tier, attn_lens, wr_tier, wr_idx, wr_off,
+            sink_local, sink_remote, window, use_kernel, tuner)
+        x = params["embed"][tokens]
+        h0 = x
+        k_every = cfg.hybrid_attn_every
+        n_groups = cfg.n_layers // k_every
+        n_blocks = max(1, cfg.hybrid_shared_blocks)
+        convs, states = [], []
+        for g_idx in range(n_groups):
+            sp = layer_slice(params["shared"], g_idx % n_blocks)
+            z = jnp.concatenate([x, h0], axis=-1) @ sp["concat_proj"]
+            zn = L.norm(cfg, z, sp, "ln1")
+            attn = _gqa_attend(cfg, sp, zn, positions, g_idx, window,
+                               use_kernel, write_and_attend, tuner)
+            z = z + _mm(attn, sp["wo"], window, use_kernel, tuner)
+            z = z + L.mlp_block(cfg, L.norm(cfg, z, sp, "ln2"), sp, mm=kmm)
+            x = x + z
+            for j in range(k_every):
+                li = g_idx * k_every + j
+                lp = layer_slice(params["layers"], li)
+                hn = L.norm(cfg, x, lp, "ln1")
+                y, conv_i, state_i = S.ssm_block_decode(
+                    cfg, hn, lp, cache["conv"][li], cache["state"][li], mm=kmm)
+                x = x + y
+                convs.append(conv_i)
+                states.append(state_i)
+        logits = _head(cfg, params, x, window, use_kernel, tuner)
+        return (logits, {"conv": jnp.stack(convs), "state": jnp.stack(states)},
+                pools)
+
+    params = fetch_remote_shards(params, mesh, mesh_axis)
+    return on_every_device(body, mesh)(
+        params, cache, pools, tokens, positions, attn_lens, table, tier,
+        wr_tier, wr_idx, wr_off)
 
 
 def _layer_row(new: jax.Array, cache_ref: jax.Array) -> jax.Array:

@@ -133,7 +133,6 @@ def test_paged_flashattn_bf16():
 
 def test_broadcast_remote_shard_map():
     """Fetch-once-broadcast: all_gather of the sharded host partition."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("model",))
     w = tiering.partition(jnp.arange(32.0).reshape(4, 8), 0.5, axis=0)
@@ -142,7 +141,7 @@ def test_broadcast_remote_shard_map():
         return ops.broadcast_remote(
             tiering.TieredArray(local, remote, axis=0), "model").materialize()
 
-    out = shard_map(f, mesh=mesh,
+    out = jax.shard_map(f, mesh=mesh,
                     in_specs=(P(None, None), P("model", None)),
-                    out_specs=P(None, None), check_rep=False)(w.local, w.remote)
+                    out_specs=P(None, None), check_vma=False)(w.local, w.remote)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(w.materialize()))

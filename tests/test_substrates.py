@@ -207,11 +207,10 @@ def test_error_feedback_reduces_bias():
 
 
 def test_compressed_psum_single_device():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(2), (32,))
-    out = shard_map(lambda v: collectives.compressed_psum(v, "data"),
+    out = jax.shard_map(lambda v: collectives.compressed_psum(v, "data"),
                     mesh=mesh, in_specs=P(None), out_specs=P(None),
-                    check_rep=False)(x)
+                    check_vma=False)(x)
     assert float(jnp.max(jnp.abs(out - x))) < 0.05 * float(jnp.max(jnp.abs(x)))
