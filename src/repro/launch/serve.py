@@ -374,7 +374,7 @@ def main(argv: list[str] | None = None) -> dict:
           f"TTFT p50 {stats.ttft_p50*1e3:.1f} ms p95 {stats.ttft_p95*1e3:.1f} ms | "
           f"queue p95 {stats.queue_delay_p95*1e3:.1f} ms | "
           f"e2e p95 {stats.e2e_p95*1e3:.1f} ms | "
-          f"prefill {stats.prefill_time:.2f}s")
+          f"prefill to first tokens {stats.prefill_time:.2f}s")
     peak = peak_device_bytes()
     if peak is not None:
         print(f"peak device memory {peak / 1e9:.3f} GB")
@@ -430,6 +430,7 @@ def main(argv: list[str] | None = None) -> dict:
         print(f"wrote {args.bench_json}")
     if args.trace_out:
         recorder.save(args.trace_out)
+        recorder.close()
         print(f"wrote {args.trace_out} "
               f"({len(recorder.events)} trace events)")
     if args.metrics_out:

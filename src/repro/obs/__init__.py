@@ -1,7 +1,10 @@
 """End-to-end observability for the DAK serving stack.
 
 * :mod:`repro.obs.trace` — Chrome trace-event (Perfetto-loadable) span /
-  counter recorder the engine's step loop emits into;
+  counter recorder the engine's step loop emits into; its phases are also
+  ``engine:<phase>`` profiler annotations;
+* :mod:`repro.obs.compiles` — the recorder's compile meter: JAX's build
+  time booked to the engine phase open at the time;
 * :mod:`repro.obs.metrics` — the unified metrics registry (counters /
   gauges / histograms, Prometheus text + JSON snapshot) that produces
   ``BENCH_serving.json``'s stats block;

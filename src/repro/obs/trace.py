@@ -6,9 +6,9 @@ The recorder produces the `Chrome trace-event format
 (load the file in Perfetto / ``chrome://tracing``), organised as:
 
 * an **engine** process — one *step* track carrying the per-step phase
-  spans (``admission``, ``prefill[rid]`` chunks, ``decode``, ``migrate``,
-  ``replan``) plus instant markers for elastic events, health transitions
-  and preemptions;
+  spans (``step``, ``admission``, ``prefill[rid]`` chunks, ``decode`` and
+  the phases nested in them, ``compile``) plus instant markers for
+  elastic events, health transitions, runtime actions and preemptions;
 * a **links** process — counter tracks: per-host-link achieved bytes,
   the AIMD window, queue depth, elastic local deficit, and the numeric
   health state;
@@ -21,14 +21,29 @@ or modeled seconds, written as trace microseconds), so a modeled-clock
 trace replay produces a timeline in *modeled* time — the bandwidth /
 overlap story the paper's figures tell, reconstructable per step.
 
+The engine's phases open with :meth:`TraceRecorder.phase`.  On a
+:class:`ChromeTraceRecorder` a phase is also a
+``jax.profiler.TraceAnnotation`` named ``engine:<phase>``, so under
+``jax.profiler`` it lands on the profile's host plane on the device ops'
+time base; it adds nothing to the compiled programs.  The recorder's
+`obs.compiles.CompileMeter` books what JAX builds to the phase open at
+the time.
+
 :data:`NULL_RECORDER` is the engine's default: every emission method is a
-no-op and ``enabled`` is False, so the serving path stays bitwise
-identical when tracing is off (the parity tests pin this).
+no-op, ``phase()`` returns one shared no-op context and ``enabled`` is
+False, so the serving path stays bitwise identical when tracing is off
+(the parity tests pin this).
 """
 from __future__ import annotations
 
+import contextlib
 import json
-from typing import Any
+import time
+from typing import Any, Callable
+
+import jax
+
+from repro.obs.compiles import CompileMeter
 
 TRACE_SCHEMA_VERSION = 1
 
@@ -65,10 +80,22 @@ class TraceRecorder:
     def name_thread(self, pid: int, tid: int, name: str) -> None:
         """Label a track (emitted once per (pid, tid))."""
 
+    def phase(self, name: str, *, label: str | None = None, cat: str = "phase",
+              **args: Any) -> contextlib.AbstractContextManager:
+        """Context manager around one engine phase: a span on the engine's
+        step track named ``label`` (default ``name``) when it exits.  It
+        yields the span's ``args``, which the block may add to; the null
+        recorder returns one shared no-op context, which yields None."""
+        return _NO_PHASE
+
     def save(self, path: str) -> None:
         """Write the trace JSON (no-op on the null recorder)."""
 
+    def close(self) -> None:
+        """Release what the recorder holds outside itself."""
 
+
+_NO_PHASE = contextlib.nullcontext()
 NULL_RECORDER = TraceRecorder()
 
 
@@ -84,6 +111,38 @@ class ChromeTraceRecorder(TraceRecorder):
         for pid, name in _PROCESS_NAMES.items():
             self.events.append({"ph": "M", "name": "process_name",
                                 "pid": pid, "tid": 0, "args": {"name": name}})
+        # Phase timestamps; the engine points this at its own clock.
+        self.clock: Callable[[], float] = time.time
+        self._open: list[tuple[str, float]] = []     # (name, start), outermost first
+        self.meter = CompileMeter(self._phase_path, self._compile_span)
+
+    def _phase_path(self) -> str:
+        return "/".join(name for name, _ in self._open)
+
+    @contextlib.contextmanager
+    def phase(self, name: str, *, label: str | None = None, cat: str = "phase",
+              **args: Any):
+        t0 = self.clock()
+        self._open.append((name, t0))
+        try:
+            with jax.profiler.TraceAnnotation(f"engine:{name}", **args):
+                yield args
+        finally:
+            self._open.pop()
+        self.span(ENGINE, 0, label or name, t0, self.clock(), cat=cat, **args)
+
+    def _compile_span(self, path: str, event: str, seconds: float,
+                      exclusive: float, fun: str) -> None:
+        """One ``compile`` span ending now, kept inside the phase it is
+        booked to (a modeled clock does not advance while JAX builds)."""
+        t1 = self.clock()
+        floor = self._open[-1][1] if self._open else 0.0
+        self.span(ENGINE, 0, "compile", max(floor, t1 - seconds), t1,
+                  cat="compile", phase=path, event=event, fun=fun,
+                  exclusive_s=exclusive)
+
+    def close(self) -> None:
+        self.meter.close()
 
     @staticmethod
     def _us(t: float) -> float:

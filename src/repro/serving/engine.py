@@ -155,6 +155,8 @@ class EngineStats:
     generated_tokens: int = 0              # tokens actually emitted (all reqs)
     decode_steps: int = 0
     decode_time: float = 0.0
+    # Host seconds of every prefill chunk from its dispatch; a prompt's
+    # last chunk ends after the first-token sync, so the device has run it.
     prefill_time: float = 0.0
     local_pages_hwm: int = 0               # peak pages resident per tier
     remote_pages_hwm: int = 0
@@ -420,6 +422,7 @@ class ServingEngine:
         at the trace recorder.  The hooks default to None, so with tracing
         off neither component ever makes a call."""
         rec = self.recorder
+        rec.clock = self.clock.now         # phases stamp the engine's clock
         rec.name_thread(ENGINE, 0, "step")
 
         def on_health(event: str, **info) -> None:
@@ -589,32 +592,33 @@ class ServingEngine:
         request's private cache.  The last chunk commits: first token
         sampled from the chunk's final logits, cache written to the slot
         (paged pools / reference cache), request joins the decode batch."""
-        req = ps.req
+        req, rec = ps.req, self.recorder
         self._prefill_calls_step += 1
         t0 = time.time()
-        tc0 = self.clock.now() if self.recorder.enabled else 0.0
-        chunk = jnp.asarray(req.prompt[ps.pos:ps.pos + n], jnp.int32)[None, :]
-        if ps.pos == 0 and n == len(req.prompt):
-            ps.logits, ps.cache = M.prefill(
-                self.cfg, self._fetched_params(), {"tokens": chunk},
-                max_len=self.max_len)
-        else:
-            if ps.cache is None:           # first chunk of a split prompt
-                ps.cache = M.init_cache(self.cfg, 1, self.max_len, self._dtype)
-            ps.logits, ps.cache = M.prefill_chunk(
-                self.cfg, self._fetched_params(), ps.cache, chunk, ps.pos)
-            self.stats.prefill_chunks += 1
-        ps.pos += n
-        self.stats.prefill_time += time.time() - t0
-        self._clock_tick_prefill(n)
-        if self.recorder.enabled:
-            self.recorder.span(ENGINE, 0, f"prefill[{req.rid}]", tc0,
-                               self.clock.now(), cat="prefill", slot=slot,
-                               tokens=n, pos=ps.pos)
+        with rec.phase("prefill", label=f"prefill[{req.rid}]", cat="prefill",
+                       rid=req.rid, slot=slot, tokens=n, pos=ps.pos + n):
+            chunk = jnp.asarray(req.prompt[ps.pos:ps.pos + n], jnp.int32)[None, :]
+            if ps.pos == 0 and n == len(req.prompt):
+                ps.logits, ps.cache = M.prefill(
+                    self.cfg, self._fetched_params(), {"tokens": chunk},
+                    max_len=self.max_len)
+            else:
+                if ps.cache is None:       # first chunk of a split prompt
+                    ps.cache = M.init_cache(self.cfg, 1, self.max_len, self._dtype)
+                ps.logits, ps.cache = M.prefill_chunk(
+                    self.cfg, self._fetched_params(), ps.cache, chunk, ps.pos)
+                self.stats.prefill_chunks += 1
+            ps.pos += n
+            self._clock_tick_prefill(n)
         if ps.pos < len(req.prompt):
+            self.stats.prefill_time += time.time() - t0
             return
         del self.prefilling[slot]
-        nxt = int(jnp.argmax(ps.logits[0, -1]))
+        # The first-token sync: the prompt's last chunk has run on the
+        # device when it returns, so prefill_time ends after it.
+        with rec.phase("prefill.sync", rid=req.rid):
+            nxt = int(jnp.argmax(ps.logits[0, -1]))
+        self.stats.prefill_time += time.time() - t0
         req.out_tokens.append(nxt)
         self.stats.generated_tokens += 1
         req.t_first = self.clock.now()
@@ -642,7 +646,9 @@ class ServingEngine:
             # (a no-op in the same-step whole-prompt case: nothing could
             # allocate between the admission check and this one).
             self._maybe_preempt(req)
-        self._write_slot_cache(slot, ps.cache, len(req.prompt))
+        with rec.phase("kv_write", rid=req.rid,
+                       pages=-(-len(req.prompt) // self.page_size)):
+            self._write_slot_cache(slot, ps.cache, len(req.prompt))
         self.lens[slot] = len(req.prompt)
         self._next_tok[slot, 0] = nxt
         self.active[slot] = req
@@ -985,10 +991,8 @@ class ServingEngine:
 
         Pool growth (`grow_remote`) and sink moves change pool shapes, so
         they key the cache alongside the window bucket — a changed key is
-        a fresh compile, counted and visible as a `compile` span.
-
-        Returns ``(fn, bucket)`` — ``bucket`` is a label on a fresh
-        compile, None on a cache hit."""
+        a fresh compile, counted in ``compile_count`` (and, with a
+        recorder, booked to the ``decode`` phase by its compile meter)."""
         wb = self._bucket_window(self.window)
         if self.pcache is not None:
             sl, sr = self.pcache.sink_local, self.pcache.sink_remote
@@ -1001,7 +1005,7 @@ class ServingEngine:
         fn = self._compiled.get(key)
         if fn is not None:
             self.compile_cache_hits += 1
-            return fn, None
+            return fn
         self.compile_count += 1
         cfg, mesh, axis = self.cfg, self.mesh, self.mesh_axis
         tuner = self.tuner
@@ -1036,13 +1040,33 @@ class ServingEngine:
                 return tok, cache
             fn = jax.jit(run, donate_argnums=(1,))
         self._compiled[key] = fn
-        return fn, f"{kind}/w{wb}"
+        return fn
 
     def step(self) -> None:
         """One decode step for all active slots (ragged: each slot at its
         own position).  With the adaptive runtime attached, the in-flight
         DMA window is re-read from the controller every step and a
-        telemetry sample is reported after the compute."""
+        telemetry sample is reported after the compute.
+
+        With a recorder the step opens these phases (`TraceRecorder.phase`),
+        each a span on the engine track and an ``engine:<phase>`` profiler
+        annotation::
+
+            step
+              admission            prompts prefilled this step
+                prefill            one chunk's dispatch (rid, slot, tokens)
+                prefill.sync       the first-token readback (rid)
+                kv_write           the prompt's KV into its pages (rid, pages)
+              decode               the decode step (step, slots)
+                decode.prep        pages, write targets, tables, pools
+                decode.wait        waiting for the step's tokens
+              finish               runtime hook, token readback, bookkeeping
+        """
+        with self.recorder.phase("step"):
+            self._step()
+
+    def _step(self) -> None:
+        rec = self.recorder
         t_step_clock = self.clock.now()    # engine-clock step origin: wall
         #                                    seconds on WallClock, modeled
         #                                    seconds on ModeledClock replays
@@ -1056,32 +1080,31 @@ class ServingEngine:
             self._pending_shrink = None
             self.shrink_local_budget(frac)
         self._elastic_step()               # drain any local-budget deficit
-        t_admit0 = self.clock.now() if self.recorder.enabled else 0.0
-        prefill_tokens = self._admit()
+        with rec.phase("admission", cat="sched") as adm:
+            prefill_tokens = self._admit()
+            if adm is not None:            # the span's args, written at exit
+                adm["prefill_tokens"] = prefill_tokens
         if self._jit:
             # The compiled step gathers the sharded partitions itself: free
             # the prefills' gathered copy (a whole remote tier per device
             # under a mesh) before it runs.
             self._step_params = None
-        if self.recorder.enabled:
-            self.recorder.span(ENGINE, 0, "admission", t_admit0,
-                               self.clock.now(), cat="sched",
-                               prefill_tokens=prefill_tokens)
         if not any(r is not None for r in self.active):
-            if prefill_tokens:
-                self._runtime_step(t_step_clock, prefill_tokens,
-                                   np.zeros(self.max_batch, dtype=bool))
-            elif not self.prefilling and self.scheduler.waiting:
-                # Idle but a trace arrival is pending: fast-forward the
-                # modeled clock to it (no-op on the wall clock, which
-                # just polls until the arrival time comes to pass).
-                nxt = self.scheduler.next_arrival()
-                if nxt is not None:
-                    self.clock.advance(max(0.0, nxt - self.clock.now()))
-            self._finish_step_health()
-            if self.flight is not None:
-                self.flight.record(self._flight_snapshot())
-            self._audit_page_table()
+            with rec.phase("finish"):
+                if prefill_tokens:
+                    self._runtime_step(t_step_clock, prefill_tokens,
+                                       np.zeros(self.max_batch, dtype=bool))
+                elif not self.prefilling and self.scheduler.waiting:
+                    # Idle but a trace arrival is pending: fast-forward the
+                    # modeled clock to it (no-op on the wall clock, which
+                    # just polls until the arrival time comes to pass).
+                    nxt = self.scheduler.next_arrival()
+                    if nxt is not None:
+                        self.clock.advance(max(0.0, nxt - self.clock.now()))
+                self._finish_step_health()
+                if self.flight is not None:
+                    self.flight.record(self._flight_snapshot())
+                self._audit_page_table()
             return
         active = np.array([r is not None for r in self.active])
         if self.pcache is not None:
@@ -1091,9 +1114,41 @@ class ServingEngine:
             self.pcache.touch_step(self.lens, active)
         tokens = jnp.asarray(self._next_tok)
         positions = np.where(active, self.lens, 0).astype(np.int32)
-        tc0 = self.clock.now() if self.recorder.enabled else 0.0
+        with rec.phase("decode", cat="decode", slots=int(active.sum()),
+                       step=self.stats.decode_steps + 1):
+            tok_dev = self._decode(active, tokens, positions)
+        with rec.phase("finish"):
+            self._runtime_step(t_step_clock, prefill_tokens, active)
+            self._finish_step_health()
+            nxt = np.asarray(tok_dev, dtype=np.int32)
+            for slot, req in enumerate(self.active):
+                if req is None:
+                    continue
+                tok = int(nxt[slot])
+                req.out_tokens.append(tok)
+                self.stats.generated_tokens += 1
+                self.lens[slot] += 1
+                done = (len(req.out_tokens) >= req.max_new_tokens
+                        or tok == req.eos_id
+                        or self.lens[slot] >= self.max_len - 1)
+                if done:
+                    self._finish_request(req)
+                    self.active[slot] = None
+                    self.lens[slot] = 0
+                    if self.pcache is not None:
+                        self.pcache.free_slot(slot)
+                else:
+                    self._next_tok[slot, 0] = tok
+            if self.flight is not None:
+                self.flight.record(self._flight_snapshot())
+            self._audit_page_table()
+
+    def _decode(self, active: np.ndarray, tokens: jax.Array,
+                positions: np.ndarray) -> jax.Array:
+        """Run the decode step over the active slots and return the [B]
+        sampled tokens on the device (synced on a wall clock)."""
+        rec = self.recorder
         t0 = time.time()
-        bucket = None                      # compile-span label on a fresh jit
         if not self.tiered:
             logits, self.cache = M.decode_step(
                 self.cfg, self.params, self.cache, tokens,
@@ -1104,7 +1159,7 @@ class ServingEngine:
             # jitted path passes the raw params so the fetch-once broadcast
             # traces *inside* the compiled step (identity off-mesh).
             if self._jit:
-                fn, bucket = self._compiled_step("ssm")
+                fn = self._compiled_step("ssm")
                 tok_dev, self.cache = fn(self.params, self.cache, tokens)
             else:
                 logits, self.cache = TD.tiered_ssm_decode_step(
@@ -1114,18 +1169,19 @@ class ServingEngine:
                     tuner=self.tuner)
                 tok_dev = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
         else:
-            for slot in np.nonzero(active)[0]:
-                self._ensure_capacity_elastic(int(slot), int(self.lens[slot]) + 1)
-            self._note_occupancy()
-            wr_tier, wr_idx, wr_off = self.pcache.write_targets(self.lens, active)
-            table, tier = self.pcache.device_tables()
-            attn_lens = np.where(active, self.lens + 1, 0).astype(np.int32)
-            paged_args = (tokens, jnp.asarray(positions), jnp.asarray(attn_lens),
-                          table, tier, wr_tier, wr_idx, wr_off)
-            pools_in = self.pcache.compute_pools()
+            with rec.phase("decode.prep"):
+                for slot in np.nonzero(active)[0]:
+                    self._ensure_capacity_elastic(int(slot), int(self.lens[slot]) + 1)
+                self._note_occupancy()
+                wr_tier, wr_idx, wr_off = self.pcache.write_targets(self.lens, active)
+                table, tier = self.pcache.device_tables()
+                attn_lens = np.where(active, self.lens + 1, 0).astype(np.int32)
+                paged_args = (tokens, jnp.asarray(positions), jnp.asarray(attn_lens),
+                              table, tier, wr_tier, wr_idx, wr_off)
+                pools_in = self.pcache.compute_pools()
             if self.cfg.family == "hybrid":
                 if self._jit:
-                    fn, bucket = self._compiled_step("hybrid")
+                    fn = self._compiled_step("hybrid")
                     tok_dev, self.cache, pools_out = fn(
                         self.params, self.cache, pools_in, *paged_args)
                 else:
@@ -1139,7 +1195,7 @@ class ServingEngine:
                         tuner=self.tuner)
                     tok_dev = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
             elif self._jit:
-                fn, bucket = self._compiled_step("paged")
+                fn = self._compiled_step("paged")
                 tok_dev, pools_out = fn(self.params, pools_in, *paged_args)
             else:
                 logits, pools_out = TD.paged_tiered_decode_step(
@@ -1153,44 +1209,14 @@ class ServingEngine:
             self.pcache.commit_pools(pools_out)
         if self.clock.kind == "wall":
             # Host sync only where wall-clock timing needs it; modeled-clock
-            # replays dispatch fully async (the [B] int32 token fetch below
-            # is the step's only device dependency).
-            jax.block_until_ready(tok_dev)
+            # replays dispatch fully async (the [B] int32 token fetch in
+            # `finish` is the step's only device dependency).
+            with rec.phase("decode.wait"):
+                jax.block_until_ready(tok_dev)
         self.stats.decode_time += time.time() - t0
         self.stats.decode_steps += 1
         self._clock_tick_decode(active)
-        if self.recorder.enabled:
-            if bucket is not None:
-                self.recorder.span(ENGINE, 0, f"compile[{bucket}]", tc0,
-                                   self.clock.now(), cat="compile",
-                                   wall_ms=(time.time() - t0) * 1e3)
-            self.recorder.span(ENGINE, 0, "decode", tc0, self.clock.now(),
-                               cat="decode", slots=int(active.sum()),
-                               step=self.stats.decode_steps)
-        self._runtime_step(t_step_clock, prefill_tokens, active)
-        self._finish_step_health()
-        nxt = np.asarray(tok_dev, dtype=np.int32)
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            tok = int(nxt[slot])
-            req.out_tokens.append(tok)
-            self.stats.generated_tokens += 1
-            self.lens[slot] += 1
-            done = (len(req.out_tokens) >= req.max_new_tokens
-                    or tok == req.eos_id
-                    or self.lens[slot] >= self.max_len - 1)
-            if done:
-                self._finish_request(req)
-                self.active[slot] = None
-                self.lens[slot] = 0
-                if self.pcache is not None:
-                    self.pcache.free_slot(slot)
-            else:
-                self._next_tok[slot, 0] = tok
-        if self.flight is not None:
-            self.flight.record(self._flight_snapshot())
-        self._audit_page_table()
+        return tok_dev
 
     def _runtime_step(self, t_step_clock: float, prefill_tokens: int,
                       active: np.ndarray) -> None:

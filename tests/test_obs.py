@@ -13,9 +13,11 @@ snapshot is the violating step.
 """
 from __future__ import annotations
 
+import collections
 import copy
 import json
 import os
+import re
 import sys
 
 import jax
@@ -182,6 +184,159 @@ def test_validate_trace_catches_malformed_events():
     errors = validate_trace(doc)
     assert any("missing keys" in e for e in errors)
     assert any("unknown phase" in e for e in errors)
+
+
+# ---------------------------------------------------------------------------
+# Engine phases on the profiler's clock, the compile meter, the HLO
+# ---------------------------------------------------------------------------
+# Each phase's enclosing phase (None: the outermost).
+_PARENT = {"step": None, "admission": "step", "decode": "step",
+           "finish": "step", "prefill": "admission",
+           "prefill.sync": "admission", "kv_write": "admission",
+           "decode.prep": "decode", "decode.wait": "decode"}
+
+
+def _wall_engine(recorder=None):
+    """A wall-clock engine (FCFS, whole prompts, compiled decode step)
+    with 3 requests for 2 slots, so one request is admitted mid-run."""
+    eng = ServingEngine(_CFG, _PARAMS, max_batch=2, max_len=32,
+                        global_offload_ratio=0.5, page_size=4,
+                        recorder=recorder)
+    rng = np.random.default_rng(1)
+    for i in range(3):
+        eng.submit(Request(rid=i, max_new_tokens=3,
+                           prompt=rng.integers(3, _CFG.vocab, 6 + 2 * i
+                                               ).astype(np.int32)))
+    return eng
+
+
+def _profiled_engine_spans(tmp_path, eng):
+    """Run ``eng`` to completion under jax.profiler; return the
+    ``engine:`` events of the profile by plane name, and the step count."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        steps = 0
+        while eng.scheduler.waiting or any(r is not None for r in eng.active):
+            eng.step()
+            steps += 1
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    by_plane: dict[str, list] = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine:"):
+                    by_plane.setdefault(plane.name, []).append(
+                        (e.name[len("engine:"):], e.start_ns, e.end_ns,
+                         line.name, dict(e.stats)))
+    return by_plane, steps
+
+
+def test_engine_phases_nest_on_the_profiler_host_plane(tmp_path):
+    rec = ChromeTraceRecorder()
+    eng = _wall_engine(rec)
+    by_plane, steps = _profiled_engine_spans(tmp_path, eng)
+    rec.close()
+    assert list(by_plane) == ["/host:CPU"]
+    spans = sorted(by_plane["/host:CPU"], key=lambda s: (s[1], -s[2]))
+    assert {s[0] for s in spans} == set(_PARENT)
+    assert len({s[3] for s in spans}) == 1          # one thread's line
+    for name, start, end, _, _ in spans:
+        enclosing = [s for s in spans
+                     if s[1] <= start and end <= s[2] and s[:3] != (name, start, end)]
+        parent = max(enclosing, key=lambda s: s[1])[0] if enclosing else None
+        assert parent == _PARENT[name], (name, parent)
+    count = collections.Counter(s[0] for s in spans)
+    assert count["step"] == steps
+    assert count["decode"] == eng.stats.decode_steps
+    assert count["prefill.sync"] == 3 == eng.stats.served
+    assert {s[4]["rid"] for s in spans if s[0] in ("prefill", "kv_write")} == {0, 1, 2}
+    # The Chrome spans hold the same intervals, on the engine's clock.
+    chrome = [e for e in rec.events if e["ph"] == "X" and e["pid"] == ENGINE
+              and e["name"] != "compile"]
+    chrome_by: dict[str, list] = collections.defaultdict(list)
+    for e in chrome:
+        chrome_by["prefill" if e["name"].startswith("prefill[") else e["name"]
+                  ].append((e["ts"] * 1e3, (e["ts"] + e["dur"]) * 1e3))
+    prof_by: dict[str, list] = collections.defaultdict(list)
+    for name, start, end, _, _ in spans:
+        prof_by[name].append((start, end))
+    assert {k: len(v) for k, v in chrome_by.items()} == {
+        k: len(v) for k, v in prof_by.items()}
+    pairs = [(c, p) for k in prof_by
+             for c, p in zip(sorted(chrome_by[k]), sorted(prof_by[k]))]
+    offset = float(np.median([c[0] - p[0] for c, p in pairs]))
+    for c, p in pairs:                      # ns, after aligning the clocks
+        assert abs(c[0] - offset - p[0]) < 20e6 and abs(c[1] - offset - p[1]) < 20e6
+
+
+def _lowered_decode_step(recorder):
+    """The StableHLO of the engine's compiled paged decode step, lowered
+    inside the call, where the engine's phases are open."""
+    eng = _wall_engine(recorder)
+    texts = []
+    compiled_step = eng._compiled_step
+
+    def spy(kind):
+        fn = compiled_step(kind)
+
+        def call(*args):
+            texts.append(fn.lower(*args).as_text(debug_info=True))
+            return fn(*args)
+        return call
+
+    eng._compiled_step = spy
+    eng.step()
+    recorder.close()
+    assert texts
+    return texts[0]
+
+
+def test_phases_leave_the_compiled_decode_step_unchanged():
+    # one call site for both, as the source locations are in the text
+    traced, untraced = [_lowered_decode_step(rec)
+                        for rec in (ChromeTraceRecorder(), NULL_RECORDER)]
+    assert traced == untraced
+
+
+def test_kernel_names_the_benchmark_reads_name_the_jitted_wrappers():
+    """The chip benchmark finds the kernels in the device trace by these
+    names (``splitk_gemm_roofline`` and its breakdown): a rename of the
+    jitted wrappers has to fail here first."""
+    text = _lowered_decode_step(NULL_RECORDER)
+    for name in ("splitk_gemm", "paged_splitk_flashattn"):
+        assert re.search(rf"func\.func private @{name}(_\d+)?\(", text), name
+
+
+def test_compile_meter_books_builds_to_their_phase():
+    from jax._src import monitoring
+
+    listeners = len(monitoring.get_event_duration_listeners())
+    rec = ChromeTraceRecorder()
+    assert len(monitoring.get_event_duration_listeners()) == listeners + 1
+    eng = _wall_engine(rec)
+    meter = rec.meter
+    built = set(meter.counts)              # the engine's construction
+    assert {path for path, _ in built} <= {"outside"}
+    eng.step()                        # two eager prefills, a fresh decode bucket
+    assert eng.compile_count == 1
+    assert meter.counts[("step/decode", "jaxpr_trace")] >= 1   # and nested jits
+    assert meter.counts[("step/decode", "lower")] == 1
+    assert meter.counts[("step/decode", "backend_compile")] == 1
+    assert meter.seconds(under="decode") > 0
+    assert meter.seconds(under="admission") > 0
+    assert all(path.startswith("step/") for path, _ in set(meter.counts) - built)
+    spans = [e for e in rec.events if e["ph"] == "X" and e["name"] == "compile"]
+    assert len(spans) == sum(meter.counts.values())
+    assert {e["args"]["phase"] for e in spans} == {p for p, _ in meter.counts}
+    booked = dict(meter.counts)
+    rec.close()
+    assert len(monitoring.get_event_duration_listeners()) == listeners
+    jax.jit(lambda x: x * 3 + 1)(np.arange(5))      # a build nobody books
+    assert dict(meter.counts) == booked
 
 
 # ---------------------------------------------------------------------------
