@@ -46,17 +46,26 @@ flash_prefill = importlib.import_module("repro.kernels.flash_prefill")
 
 @dataclasses.dataclass(frozen=True)
 class GemmLaunch:
-    """Geometry of one ``splitk_gemm`` dispatch (already padded to blocks)."""
+    """Geometry of one ``splitk_gemm`` dispatch (already padded to blocks).
+    A block left None is the kernel's own choice for these shapes
+    (`splitk_gemm.gemm_blocks`)."""
     name: str
     m: int
     k: int
     n_loc: int
     n_rem: int
-    block_m: int = splitk_gemm.DEFAULT_BLOCK_M
-    block_n: int = splitk_gemm.DEFAULT_BLOCK_N
-    block_k: int = splitk_gemm.DEFAULT_BLOCK_K
+    block_m: int | None = None
+    block_n: int | None = None
+    block_k: int | None = None
     window: int = splitk_gemm.DEFAULT_WINDOW
     dtype_bytes: int = 4
+
+    def __post_init__(self):
+        auto = splitk_gemm.gemm_blocks(self.m, self.k, self.n_loc, self.n_rem,
+                                       self.dtype_bytes)
+        for field, v in zip(("block_m", "block_n", "block_k"), auto):
+            if getattr(self, field) is None:
+                object.__setattr__(self, field, v)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,7 +123,8 @@ def check_gemm_launch(launch: GemmLaunch, hw: HardwareSpec, *,
         return out
     # DAK101: windowed VMEM working set vs the hardware profile.
     fp = splitk_gemm.vmem_footprint_bytes(
-        launch.m, launch.k, block_m=bm, block_n=bn, block_k=bk,
+        launch.m, launch.k, launch.n_loc + launch.n_rem,
+        block_m=bm, block_n=bn, block_k=bk,
         window=launch.window, dtype_bytes=launch.dtype_bytes)
     if fp > hw.vmem_bytes:
         out.append(Finding(
@@ -350,7 +360,7 @@ def describe_launches(
     With a ``tuner`` (`kernels.autotune.Autotuner`) the descriptors carry
     the *autotuned* block shapes — the exact geometry the engine would
     dispatch with that tuner attached — so the DAK101-103 checks run over
-    tuned launches, not just the module defaults."""
+    tuned launches, not just the kernels' own choices."""
     window = max(1, plan.window.n_inflight)
     dt = _dtype_name(dtype_bytes)
     gemms: list[GemmLaunch] = []
@@ -368,9 +378,8 @@ def describe_launches(
         k = shape[-2]
         align_eff = math.lcm(od.align if od.align is not None else align, mesh_div)
         n_loc, n_rem = tiering.split_sizes(dim, ratio, align_eff)
-        bm = splitk_gemm.DEFAULT_BLOCK_M
-        bn = splitk_gemm.DEFAULT_BLOCK_N
-        bk = splitk_gemm.DEFAULT_BLOCK_K
+        bm, bn, bk = splitk_gemm.gemm_blocks(batch, k, n_loc, n_rem,
+                                             dtype_bytes)
         if tuner is not None and n_loc and n_rem:
             tuned = tuner.best_gemm(batch, k, n_loc, n_rem, dt)
             if tuned is not None:

@@ -32,6 +32,12 @@ from typing import Protocol
 
 from repro.core.hardware import HardwareSpec
 
+# Bytes of one weight chunk the direct-access kernels move per async copy:
+# the plan sizes its congestion window in these chunks, and `splitk_gemm`
+# derives its weight tile from them.  1 MiB streamed StarCoder2-3B's decode
+# GEMMs fastest at window 1 on a TPU v5e (256 KiB - 2 MiB swept).
+DMA_CHUNK_BYTES = 1024 * 1024
+
 
 @dataclasses.dataclass(frozen=True)
 class CongestionModel:

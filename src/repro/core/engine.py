@@ -268,7 +268,7 @@ def plan(
     hbm_budget_bytes: float | None = None,
     global_ratio: float | None = None,
     pod_chips: int = 1,
-    dma_chunk_bytes: int = 512 * 1024,
+    dma_chunk_bytes: int = congestion.DMA_CHUNK_BYTES,
     kv_page_size: int = 16,
     mesh: MeshSpec | None = None,
 ) -> TieringPlan:

@@ -1,8 +1,10 @@
 """Shape-keyed kernel autotuner for the direct-access kernels.
 
-The SplitK kernels ship one hard-coded tile shape (``DEFAULT_BLOCK_M/N/K``,
-``DEFAULT_BLOCK_S``) regardless of arch, dtype, offload ratio, or link
-profile — but link-bound decode is exactly the regime where tile shape
+The SplitK kernels pick their tiles without a cost model — the GEMM
+derives its blocks from the call's shapes and the DMA chunk size
+(``splitk_gemm.gemm_blocks``), the attention kernels ship one
+``DEFAULT_BLOCK_S`` — whatever the arch, offload ratio, or link profile;
+but link-bound decode is exactly the regime where tile shape
 matters: every remote tile pays a fixed DMA-issue cost that only the
 in-flight window amortizes, and the padded-block waste of an oversized
 tile is charged at full link bandwidth.  This module sweeps the candidate
@@ -108,9 +110,9 @@ class Autotuner:
     """Sweeps kernel tile shapes under the EB cost model, lint-validated.
 
     ``sweep=False`` makes the tuner lookup-only: misses return ``None``
-    (callers fall back to the module defaults) instead of running a sweep —
-    the mode ``--autotune-cache`` without ``--autotune`` uses to reproduce
-    a checked-in table without growing it.
+    (callers fall back to the kernels' own choice) instead of running a
+    sweep — the mode ``--autotune-cache`` without ``--autotune`` uses to
+    reproduce a checked-in table without growing it.
     """
 
     def __init__(self, hw: HardwareSpec = TPU_V5E, *, window: int = 2,
@@ -215,7 +217,8 @@ class Autotuner:
                   dtype: str = "float32") -> dict[str, int] | None:
         """Winning (block_m, block_n, block_k) for one splitk_gemm shape,
         or None when no candidate divides the tiers / passes the lints
-        (callers keep the module defaults and the wrapper's own fallback)."""
+        (callers keep the kernel's derived blocks and the wrapper's own
+        fallback)."""
         if n_loc <= 0 or n_rem <= 0:
             return None
         key = ("splitk_gemm", (m, k, n_loc, n_rem), dtype,

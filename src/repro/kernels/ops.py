@@ -10,14 +10,17 @@ every dispatch decision is counted (``kernel`` / ``jnp`` per op) as the
 program is traced, so a caller can see how many tiered operands of a
 compiled program took the kernel and how many took the jnp path.
 
-Both tiers are HBM-resident ``pl.ANY`` operands on this toolchain: a
-``pltpu.HOST`` operand does not compile on v5e (see `kernels.splitk_gemm`).
+Both tiers are HBM-resident operands on this toolchain: a ``pltpu.HOST``
+operand does not compile on v5e (see `kernels.splitk_gemm`).
 
-``window`` — the number of in-flight remote-DMA slots — is a *per-call*
-value: the serving engine threads the adaptive runtime's AIMD-controlled
-window through every step (`runtime.controller`), so it is normalized here
-(int, >= 1) rather than assumed to be the plan-time constant.  The window
-only schedules DMA issue; results are bitwise-independent of it.
+``window`` — the number of weight copies a kernel keeps outstanding beyond
+the one it is consuming, each a chunk of about the plan's chunk size
+(`core.congestion.DMA_CHUNK_BYTES`) — is a *per-call* value: the serving
+engine threads the adaptive runtime's AIMD-controlled window through every
+step (`runtime.controller`), so it is normalized here (int, >= 1) rather
+than assumed to be the plan-time constant.  The window only schedules DMA
+issue; the tiles never depend on it, so results are bitwise-independent
+of it.
 
 `broadcast_remote` implements pod-level fetch-once-broadcast (the TMA
 multicast analogue, DESIGN.md §2): the host partition is sharded across
@@ -30,6 +33,7 @@ params tree in one ``shard_map``, called each step by
 from __future__ import annotations
 
 import contextlib
+import math
 from collections import Counter
 from contextvars import ContextVar
 
@@ -43,12 +47,7 @@ from repro.kernels.splitk_flashattn import (
     paged_splitk_flashattn,
     splitk_flashattn,
 )
-from repro.kernels.splitk_gemm import (
-    DEFAULT_BLOCK_K,
-    DEFAULT_BLOCK_M,
-    DEFAULT_BLOCK_N,
-    splitk_gemm,
-)
+from repro.kernels.splitk_gemm import gemm_blocks, splitk_gemm
 
 
 _DISPATCH: ContextVar[Counter | None] = ContextVar("dispatch", default=None)
@@ -90,33 +89,37 @@ def tiered_matmul(
     w: TieredArray | tuple[jax.Array, jax.Array],
     *,
     window: int = 2,
-    block_m: int = DEFAULT_BLOCK_M,
-    block_n: int = DEFAULT_BLOCK_N,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_m: int | None = None,
+    block_n: int | None = None,
+    block_k: int | None = None,
     use_kernel: bool = True,
     interpret: bool | None = None,
     tuner=None,
 ) -> jax.Array:
     """y = x @ W with W column-partitioned across (HBM, host) tiers.
 
-    ``tuner`` is an optional `kernels.autotune.Autotuner`: when it holds
-    (or sweeps) a lint-validated winner for this shape, the tuned blocks
-    replace the defaults.  Block resolution happens at trace time (shapes
-    are static under jit), so the tuner costs nothing per step."""
+    A block left None is `splitk_gemm.gemm_blocks`' choice for the call's
+    shapes (a weight tile of about the plan's DMA chunk).  ``tuner`` is an
+    optional `kernels.autotune.Autotuner`: when it holds (or sweeps) a
+    lint-validated winner for this shape, the tuned blocks replace the
+    derived ones.  Block resolution happens at trace time (shapes are
+    static under jit), so neither costs anything per step."""
     window = max(1, int(window))
     wl, wr = (w.local, w.remote) if isinstance(w, TieredArray) else w
     lead = x.shape[:-1]
     k = x.shape[-1]
     n_loc, n_rem = wl.shape[1], wr.shape[1]
+    m_total = math.prod(int(d) for d in lead)
     if tuner is not None and use_kernel and n_loc and n_rem:
-        m_total = 1
-        for d in lead:
-            m_total *= int(d)
         tuned = tuner.best_gemm(m_total, k, n_loc, n_rem, str(x.dtype))
         if tuned is not None:
             block_m = tuned["block_m"]
             block_n = tuned["block_n"]
             block_k = tuned["block_k"]
+    auto = gemm_blocks(m_total, k, n_loc, n_rem, wl.dtype.itemsize)
+    block_m = block_m or auto[0]
+    block_n = block_n or auto[1]
+    block_k = block_k or auto[2]
     aligned = (n_loc % block_n == 0) and (n_rem % block_n == 0)
     # Degenerate tiers (fully local / fully remote operand) take the oracle:
     # the kernel grid assumes both partitions are non-empty.

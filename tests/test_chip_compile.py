@@ -62,21 +62,27 @@ def _sds(sharding, shape, dtype=jnp.bfloat16):
 
 
 GEMMS = {
-    # name: (K, N) of the layer weight the engine splits by columns
+    # name: (K, N) of each layer weight the engine splits by columns
     "wq": (CFG.d_model, CFG.padded_heads * CFG.resolved_head_dim),
+    "wkv": (CFG.d_model, 2 * CFG.n_kv_heads * CFG.resolved_head_dim),
+    "wo": (CFG.padded_heads * CFG.resolved_head_dim, CFG.d_model),
     "wi": (CFG.d_model, CFG.d_ff),
+    "wdown": (CFG.d_ff, CFG.d_model),
 }
+DECODE_ROWS = 32
 
 
 @pytest.mark.parametrize("name", sorted(GEMMS))
 def test_splitk_gemm_compiles_for_v5e(one_chip, name):
+    """Every StarCoder2-3B decode GEMM at 32 rows, with the tiles the
+    kernel derives from its shapes and a window of 4 (five VMEM slots)."""
     k, n = GEMMS[name]
     n_loc, n_rem = split_sizes(n, 0.5, BLOCK)
     assert n_loc and n_rem
-    x = _sds(one_chip, (BLOCK, k))            # 8 decode rows, padded to a block
+    x = _sds(one_chip, (DECODE_ROWS, k))
     compiled = splitk_gemm.lower(
         x, _sds(one_chip, (k, n_loc)), _sds(one_chip, (k, n_rem)),
-        block_m=BLOCK, block_n=BLOCK, block_k=BLOCK, interpret=False).compile()
+        window=4, interpret=False).compile()
     assert CUSTOM_CALL in compiled.as_text()
 
 
