@@ -56,11 +56,13 @@ def run(man, config: dict, seed: int, finished: list, mix: dict,
            "longest": max((len(p[2]) for p in picked), default=0)}
     if not picked:
         return out
-    ref_mod = man.reference(config)
+    arch = man.reference(config)
     key = weights.base_key(seed)
-    make_layer = jax.jit(lambda i: weights.make_layer(m, key, i))
-    make_top = jax.jit(lambda name: weights.make_top(m, key, name), static_argnums=0)
-    ref = ref_mod.Reference(m, make_layer, make_top)
+    make_layer = jax.jit(lambda stack, i: weights.make_layer(arch, m, key, stack, i),
+                         static_argnums=0)
+    make_top = jax.jit(lambda name: weights.make_top(arch, m, key, name),
+                       static_argnums=0)
+    ref = arch.Reference(m, make_layer, make_top)
     seqs = [np.concatenate([p, np.asarray(t[:-1], np.int32)]) for _, p, t in picked]
     starts = [len(p) - 1 for _, p, _ in picked]
     counts = [len(t) for _, _, t in picked]

@@ -3,9 +3,16 @@
 A cell names a configuration and a traffic mix.  The configuration's
 ``file`` is a JSON file under ``configs/``; the mix is
 ``traffic/<traffic>.json``; each metric is read by ``metrics/<name>.py``;
-a configuration's reference is ``references/<reference>.py``; a cell's
-output limits are ``limits/<cell>.json``.  Adding a cell, a configuration,
-a mix or a metric adds files and entries and edits none.
+a cell's output limits are ``limits/<cell>.json``.  A configuration's
+architecture is ``references/<reference>.py``: its weight layout
+(``stacks``, ``top_shapes``, ``finish``), the keys checked against the
+program (``PROGRAM_KEYS``), the work of a decode step (``gemms``,
+``kernel_gemms``, ``kv_bytes_per_token``, ``decode_step``) and its plain
+reference (``Reference``, ``CONTROLS``).  A reader sees it as
+``Run.arch``, each step's program counters as ``Step.counters`` and the
+program's spans as ``Trace.program_spans``.  Adding a cell, a
+configuration, a mix, a metric or an architecture adds files and entries
+and edits none.
 """
 from __future__ import annotations
 

@@ -12,6 +12,6 @@ def read(run):
     t = sum(s.decode_s for s in dec)
     if not dec or t <= 0:
         return None
-    least = sum(work.least_time(*work.decode_step(run.model, s.decode_tokens, s.ctx),
-                                run.peak)[0] for s in dec)
+    least = sum(work.least_time(*run.arch.decode_step(run.model, s), run.peak)[0]
+                for s in dec)
     return 100.0 * least / t

@@ -17,6 +17,7 @@ def read(run):
     t = xplane.op_time_in(run.trace, {KERNEL}, spans)
     if t <= 0:
         return None
-    least = sum(work.gemm_least_time(run.model, by_i[int(sp.args["i"])].decode_tokens,
-                                     run.peak) for sp in spans)
+    least = sum(work.gemm_least_time(run.arch, run.model,
+                                     by_i[int(sp.args["i"])].decode_tokens, run.peak)
+                for sp in spans)
     return 100.0 * least / t

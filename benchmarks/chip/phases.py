@@ -20,21 +20,16 @@ PREFIX = "engine:"
 OUTSIDE = "outside"
 
 
-def load(path: str) -> list[xplane.Span]:
-    """The engine's spans in the profile at ``path`` (prefix stripped),
-    sorted by start, outer before inner."""
-    from jax.profiler import ProfileData
+def engine_spans(trace: xplane.Trace) -> list[xplane.Span]:
+    """The engine's spans among the trace's program spans, prefix
+    stripped, sorted by start, outer before inner."""
+    return [xplane.Span(s.name[len(PREFIX):], s.start, s.end, s.args)
+            for s in trace.program_spans if s.name.startswith(PREFIX)]
 
-    spans = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name != xplane.HOST_PLANE:
-            continue
-        for line in plane.lines:
-            spans.extend(xplane.Span(e.name[len(PREFIX):], e.start_ns, e.end_ns,
-                                     dict(e.stats))
-                         for e in line.events if e.name.startswith(PREFIX))
-    spans.sort(key=lambda s: (s.start, -s.end))
-    return spans
+
+def load(path: str) -> list[xplane.Span]:
+    """The engine's spans in the profile at ``path``."""
+    return engine_spans(xplane.load(path))
 
 
 def _path(spans: list[xplane.Span], t: float) -> str:

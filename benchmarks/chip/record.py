@@ -3,7 +3,10 @@
 Times are host wall-clock seconds (``time.time()``, the clock the engine
 stamps requests with).  A reader (``metrics/<name>.py``) defines
 ``read(run: Run) -> float | None`` and returns None where the run holds
-nothing for it to read.
+nothing for it to read.  What a reader may read: the steps and requests of
+the window, each step's program counters (``Step.counters``), the trace's
+harness and program spans (``xplane.Trace``), and the architecture module
+(``Run.arch``) that counts the work of a step.
 """
 from __future__ import annotations
 
@@ -21,6 +24,9 @@ class Step:
     decode_tokens: int      # requests that got a decode token (active slots)
     first_tokens: int       # requests admitted, i.e. given a first token
     ctx: int                # cached tokens the decode attended over, in all
+    # the change over the step of every int and float field of the
+    # engine's stats (``EngineStats``), by field name
+    counters: dict = dataclasses.field(default_factory=dict)
 
     @property
     def kind(self) -> str:
@@ -45,6 +51,7 @@ class Req:
 @dataclasses.dataclass
 class Run:
     model: dict                     # config file's "model" block
+    arch: object                    # its architecture module (references/)
     mix: dict                       # traffic file
     peak: dict                      # peaks.json row of this chip
     seconds: float                  # --seconds

@@ -1,10 +1,28 @@
-"""Plain float32 reference of a dense GQA decoder (StarCoder2, Qwen2.5).
+"""A dense GQA decoder (StarCoder2, Qwen2.5): the architecture module.
 
-Straight ``jax.numpy`` at ``Precision.HIGHEST``, no kernels, cache or
-batching tricks; it imports nothing of the serving program.  It reads its
-sizes from the config file's ``model`` block and makes its weights again
-from the seed, a layer at a time (``weights.make_layer``), upcast from the
-served dtype to float32.
+Everything the harness knows about this block is here: the weight layout
+(``stacks``, ``top_shapes``, ``finish``), the keys that tie the config
+file's ``model`` block to the program's ``ModelConfig`` (``PROGRAM_KEYS``),
+the work of a decode step (``gemms``, ``kernel_gemms``,
+``kv_bytes_per_token``, ``decode_step``) and the plain float32 reference
+(``Reference``).  A config file names this module under ``reference``.
+
+The weight layout, one stack ``layers`` of ``n_layers`` identical layers:
+
+    layers/ln1_w, ln1_b*, wq [d, Hp*hd], wkv [d, 2*K*hd] (K then V),
+           wo [Hp*hd, d], bq*, bkv*, ln2_w, ln2_b*, wi [d, m*F] (gate then
+           up for SwiGLU), wdown [F, d], bi*, bdown*      (* when present)
+    embed [V, d], final_w, final_b*, lm_head [d, V] (untied head)
+
+``Hp`` is the stored query-head count (``padded_heads``); heads past
+``n_heads`` are zero in ``wq``, ``bq`` and ``wo`` (``finish``), so they
+add nothing.
+
+The reference is straight ``jax.numpy`` at ``Precision.HIGHEST``, no
+kernels, cache or batching tricks; it imports nothing of the serving
+program.  It reads its sizes from the ``model`` block and makes its
+weights again from the seed, a layer at a time (``weights.make_layer``),
+upcast from the served dtype to float32.
 
 The published block, pre-norm and sequential:
 
@@ -33,6 +51,109 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from work import Gemm, itemsize
+
+# model-block key -> ModelConfig attribute, or (attribute, {file value:
+# program value}) where the two name a choice differently
+PROGRAM_KEYS = {
+    "n_layers": "n_layers", "d_model": "d_model", "n_heads": "n_heads",
+    "n_kv_heads": "n_kv_heads", "head_dim": "resolved_head_dim",
+    "padded_heads": "padded_heads", "d_ff": "d_ff", "vocab": "vocab",
+    "rope_theta": "rope_theta", "norm": "norm", "norm_eps": "norm_eps",
+    "qkv_bias": "qkv_bias", "tie_embeddings": "tie_embeddings", "dtype": "dtype",
+    "mlp": ("mlp", {"gelu_tanh": "gelu", "swiglu": "swiglu"}),
+}
+
+
+# -- weight layout -------------------------------------------------------------
+
+def layer_shapes(m: dict) -> dict[str, tuple[int, ...]]:
+    d, hd = m["d_model"], m["head_dim"]
+    hp, kv, ff = m["padded_heads"], m["n_kv_heads"], m["d_ff"]
+    mult = 2 if m["mlp"] == "swiglu" else 1
+    s = {"ln1_w": (d,), "wq": (d, hp * hd), "wkv": (d, 2 * kv * hd),
+         "wo": (hp * hd, d), "ln2_w": (d,), "wi": (d, mult * ff),
+         "wdown": (ff, d)}
+    if m["norm"] == "layernorm":
+        s["ln1_b"] = (d,)
+        s["ln2_b"] = (d,)
+    if m["qkv_bias"]:
+        s["bq"] = (hp * hd,)
+        s["bkv"] = (2 * kv * hd,)
+    if m["mlp_bias"]:
+        s["bi"] = (mult * ff,)
+        s["bdown"] = (d,)
+    return s
+
+
+def stacks(m: dict) -> dict[str, tuple[int, dict[str, tuple[int, ...]]]]:
+    return {"layers": (m["n_layers"], layer_shapes(m))}
+
+
+def top_shapes(m: dict) -> dict[str, tuple[int, ...]]:
+    d, v = m["d_model"], m["vocab"]
+    s = {"embed": (v, d), "final_w": (d,)}
+    if m["norm"] == "layernorm":
+        s["final_b"] = (d,)
+    if not m["tie_embeddings"]:
+        s["lm_head"] = (d, v)
+    return s
+
+
+def finish(m: dict, stack: str | None, name: str, x: jax.Array) -> jax.Array:
+    """Zero the stored query heads past ``n_heads``."""
+    if stack is None or m["padded_heads"] == m["n_heads"]:
+        return x
+    real = m["n_heads"] * m["head_dim"]
+    if name in ("wq", "bq"):
+        return x.at[..., real:].set(0)
+    if name == "wo":
+        return x.at[real:, :].set(0)
+    return x
+
+
+# -- work of a decode step -----------------------------------------------------
+
+def gemms(m: dict) -> list[Gemm]:
+    """The weight GEMMs of one decode step: per layer q, kv, o, up (gate
+    and up for SwiGLU) and down, and the LM head."""
+    d, hd, h, kv = m["d_model"], m["head_dim"], m["n_heads"], m["n_kv_heads"]
+    mult = 2 if m["mlp"] == "swiglu" else 1
+    nl = m["n_layers"]
+    return [Gemm("wq", d, h * hd, nl), Gemm("wkv", d, 2 * kv * hd, nl),
+            Gemm("wo", h * hd, d, nl), Gemm("wi", d, mult * m["d_ff"], nl),
+            Gemm("wdown", m["d_ff"], d, nl), Gemm("lm_head", d, m["vocab"], 1)]
+
+
+def kernel_gemms(m: dict) -> list[Gemm]:
+    """The GEMMs that run as tiered ``splitk_gemm`` calls: all of them but
+    a tied head, which multiplies by the embedding table the program keeps
+    whole."""
+    return [g for g in gemms(m) if not (g.name == "lm_head" and m["tie_embeddings"])]
+
+
+def kv_bytes_per_token(m: dict) -> float:
+    return float(m["n_layers"] * 2 * m["n_kv_heads"] * m["head_dim"] * itemsize(m))
+
+
+def decode_step(m: dict, step) -> tuple[float, float]:
+    """(operations, bytes) of a whole decode step (a ``record.Step``): its
+    ``decode_tokens`` active requests attending over ``ctx`` cached tokens
+    in all (the sum of their lengths, the new token included).  Bytes are
+    every weight once, the embedding rows of the batch, the KV read and the
+    KV written."""
+    batch, ctx = step.decode_tokens, step.ctx
+    b = itemsize(m)
+    weights = sum(g.count * g.k * g.n for g in gemms(m))
+    flops = 2.0 * batch * weights
+    flops += 4.0 * m["n_layers"] * m["n_heads"] * m["head_dim"] * ctx
+    nbytes = float(b * weights + b * batch * m["d_model"])
+    nbytes += kv_bytes_per_token(m) * (ctx + batch)
+    return flops, nbytes
+
+
+# -- reference -----------------------------------------------------------------
 
 HI = jax.lax.Precision.HIGHEST
 CONTROLS = ("fp8",)
@@ -130,8 +251,8 @@ def head(m: dict, rows, final_w, final_b, w_head):
 
 class Reference:
     """Reference and control logits at the positions that produced served
-    tokens.  ``make_layer(l)`` and ``make_top(name)`` give the served-dtype
-    weights again; every matmul runs in float32 at HIGHEST."""
+    tokens.  ``make_layer(stack, i)`` and ``make_top(name)`` give the
+    served-dtype weights again; every matmul runs in float32 at HIGHEST."""
 
     def __init__(self, m: dict, make_layer, make_top):
         self.m = m
@@ -165,7 +286,7 @@ class Reference:
         xs.update({c: self._quant(emb, c, 1)[toks] for c in controls})
         del emb
         for li in range(m["n_layers"]):
-            w = self._f32(self._make_layer(li))
+            w = self._f32(self._make_layer("layers", li))
             for c in xs:
                 xs[c] = self._layer(xs[c], w if c is None else self._ctrl_layer(w, c))
             del w

@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import collections
 import contextlib
+import dataclasses
 import gc
 import json
 import math
@@ -170,11 +171,13 @@ class Driver:
 
     def step(self) -> record.Step:
         eng = self.engine
-        dec0, gen0 = eng.stats.decode_time, eng.stats.generated_tokens
+        before = counters(eng.stats)
         start = time.time()
         with self.annotate("bench:step", i=self.step_no):
             eng.step()
         end = time.time()
+        after = counters(eng.stats)
+        delta = {k: v - before[k] for k, v in after.items() if k in before}
         firsts = decoded = ctx = 0
         for rid, obj in list(self.live.items()):
             rec, n = self.reqs[rid], len(obj.out_tokens)
@@ -195,14 +198,22 @@ class Driver:
             if obj.t_done:
                 rec.done = obj.t_done
                 del self.live[rid]
-        gen = eng.stats.generated_tokens - gen0
+        gen = delta["generated_tokens"]
         if gen != firsts + decoded:
             raise RuntimeError(f"step {self.step_no}: engine counted {gen} tokens, "
                                f"requests show {firsts + decoded}")
-        s = record.Step(self.step_no, start, end, eng.stats.decode_time - dec0,
-                        decoded, firsts, ctx)
+        s = record.Step(self.step_no, start, end, delta["decode_time"],
+                        decoded, firsts, ctx, delta)
         self.step_no += 1
         return s
+
+
+def counters(stats) -> dict:
+    """The program's counters: every field of the engine's stats dataclass
+    that holds an int or a float (flags, strings and lists are skipped).
+    Host reads only; nothing here waits for the device."""
+    return {f.name: v for f in dataclasses.fields(stats)
+            if type(v := getattr(stats, f.name)) in (int, float)}
 
 
 def run_cell(man: manifest.Manifest, cell: dict, seed: int, seconds: float,
@@ -218,16 +229,16 @@ def run_cell(man: manifest.Manifest, cell: dict, seed: int, seconds: float,
     import xplane
 
     config, mix = man.config(cell), man.traffic(cell)
-    m = config["model"]
+    m, arch = config["model"], man.reference(config)
     traffic.engine_max_len(mix)
     counter = CompileCounter()
 
     # -- set-up: program, weights, engine, traffic, warm-up ------------------
-    cfg = system.model_config(man.root, config)
-    system.check_layout(cfg, weights.layout(m))
+    cfg = system.model_config(man.root, config, arch)
+    system.check_layout(cfg, weights.layout(arch, m))
     if fault:
         system.wrap_decode_step(faults.FAULTS[fault])
-    engine = system.build_engine(cfg, weights.make_params(m, seed), config, mix,
+    engine = system.build_engine(cfg, weights.make_params(arch, m, seed), config, mix,
                                  recorder=trace)
     specs = traffic.generate(mix, seed)
 
@@ -329,7 +340,7 @@ def run_cell(man: manifest.Manifest, cell: dict, seed: int, seconds: float,
     drv.engine = None
     gc.collect()
 
-    run = record.Run(model=m, mix=mix, peak=peak, seconds=seconds,
+    run = record.Run(model=m, arch=arch, mix=mix, peak=peak, seconds=seconds,
                      t0=t0, t1=t1, steps=steps, requests=list(drv.reqs.values()),
                      setup_s=setup_s, memory_peak_bytes=mem, admission_s=admission_s,
                      trace_steps=trace_steps)
@@ -342,6 +353,9 @@ def run_cell(man: manifest.Manifest, cell: dict, seed: int, seconds: float,
             tr, label=lambda sp: kinds.get(int(sp.args.get("i", -1)), sp.name))
         breakdown = {"device_ops": run.reduced["device_ops"],
                      "idle_gaps": run.reduced["idle_gaps"]}
+        named = collections.Counter(sp.name for sp in tr.program_spans)
+        log("program spans in the profile: "
+            + (", ".join(f"{n} x{c}" for n, c in sorted(named.items())) or "none"))
         trace_dir.cleanup()
 
     # -- metrics ---------------------------------------------------------------

@@ -11,15 +11,6 @@ import dataclasses
 import sys
 from pathlib import Path
 
-# model-block key -> ModelConfig attribute
-_CFG_KEYS = {"n_layers": "n_layers", "d_model": "d_model", "n_heads": "n_heads",
-             "n_kv_heads": "n_kv_heads", "head_dim": "resolved_head_dim",
-             "padded_heads": "padded_heads", "d_ff": "d_ff", "vocab": "vocab",
-             "rope_theta": "rope_theta", "norm": "norm", "norm_eps": "norm_eps",
-             "qkv_bias": "qkv_bias", "tie_embeddings": "tie_embeddings",
-             "dtype": "dtype"}
-_MLP = {"gelu_tanh": "gelu", "swiglu": "swiglu"}
-
 
 def import_program(root: Path):
     src = str(root / "src")
@@ -29,16 +20,19 @@ def import_program(root: Path):
     return C
 
 
-def model_config(root: Path, config: dict):
+def model_config(root: Path, config: dict, arch):
     """The program's ModelConfig for ``config``: its arch id with the
-    stated overrides, checked key by key against the ``model`` block."""
+    stated overrides, checked against the ``model`` block key by key, for
+    every key of the architecture module's ``PROGRAM_KEYS``."""
     C = import_program(root)
     cfg = dataclasses.replace(C.get(config["arch"]), **config.get("overrides", {}))
     m = config["model"]
-    bad = {k: (m[k], getattr(cfg, a)) for k, a in _CFG_KEYS.items()
-           if getattr(cfg, a) != m[k]}
-    if _MLP[m["mlp"]] != cfg.mlp:
-        bad["mlp"] = (m["mlp"], cfg.mlp)
+    bad = {}
+    for k, attr in arch.PROGRAM_KEYS.items():
+        attr, names = (attr, None) if isinstance(attr, str) else attr
+        want = names[m[k]] if names else m[k]
+        if getattr(cfg, attr) != want:
+            bad[k] = (m[k], getattr(cfg, attr))
     if bad:
         raise ValueError(f"config file and program disagree (file, program): {bad}")
     return cfg
@@ -52,21 +46,31 @@ def check_layout(cfg, layout: dict) -> None:
 
     from repro.models import model as M
 
-    want = jax.eval_shape(
+    program = jax.eval_shape(
         lambda key: M.init_params(cfg, key, jnp.dtype(cfg.dtype)),
         jax.random.PRNGKey(0))
-    got = jax.tree.map(lambda a: tuple(a.shape), want)
-    flat_got = {"/".join(str(getattr(k, "key", k)) for k in p): v
-                for p, v in jax.tree_util.tree_flatten_with_path(
-                    got, is_leaf=lambda x: isinstance(x, tuple))[0]}
-    flat_want = {}
+    compare_layout(program, layout)
+
+
+def _flat(layout: dict, prefix: str = "") -> dict:
+    out = {}
     for k, v in layout.items():
-        if isinstance(v, dict):
-            flat_want.update({f"{k}/{kk}": vv for kk, vv in v.items()})
-        else:
-            flat_want[k] = v
-    if flat_got != flat_want:
-        diff = sorted(set(flat_got.items()) ^ set(flat_want.items()))
+        out.update(_flat(v, f"{prefix}{k}/") if isinstance(v, dict)
+                   else {f"{prefix}{k}": tuple(v)})
+    return out
+
+
+def compare_layout(program, layout: dict) -> None:
+    """``program``, a tree of arrays or shape structs such as the program's
+    parameters, must have exactly the leaves and shapes of ``layout``
+    (``weights.layout``: nested dicts of shapes)."""
+    import jax
+
+    got = {"/".join(str(getattr(k, "key", k)) for k in p): tuple(a.shape)
+           for p, a in jax.tree_util.tree_flatten_with_path(program)[0]}
+    want = _flat(layout)
+    if got != want:
+        diff = sorted(set(got.items()) ^ set(want.items()))
         raise ValueError(f"program parameter layout differs from the "
                          f"benchmark's: {diff}")
 

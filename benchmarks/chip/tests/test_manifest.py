@@ -102,7 +102,7 @@ def test_a_new_config_mix_and_metric_load_with_no_edit(tmp_path):
     names = [m["name"] for m in man.metrics(cell, "per_layer")]
     assert "steps_in_window" in names
     assert "elsewhere" not in names               # listed for another cell only
-    run = record.Run(model={}, mix={"slots": 16}, peak={}, seconds=1.0,
+    run = record.Run(model={}, arch=None, mix={"slots": 16}, peak={}, seconds=1.0,
                      t0=0.0, t1=1.0, steps=[object()] * 3, requests=[], setup_s=1.0,
                      memory_peak_bytes=None)
     metric = next(m for m in doc["per_layer"] if m["name"] == "steps_in_window")

@@ -4,6 +4,7 @@ from pathlib import Path
 
 import pytest
 
+import phases
 import xplane
 
 FIXTURE = Path(__file__).parent / "fixtures" / "two_steps.xplane.pb"
@@ -53,3 +54,36 @@ def test_instruction_names():
     assert xplane.instruction("%splitk_gemm.274 = bf16[128] custom-call()") == "splitk_gemm"
     assert xplane.instruction("%paged_splitk_flashattn = bf16[8]") == "paged_splitk_flashattn"
     assert xplane.instruction("%slice_bitcast_fusion.2 = (bf16[1])") == "slice_bitcast_fusion"
+
+
+def test_program_spans_are_kept_apart_from_the_harness_spans(tmp_path):
+    """A profile recorded here, on the CPU, with the profiler options a
+    traced run uses: the engine's annotations are program spans, and the
+    harness spans, and so the window, are the ``bench:`` ones alone."""
+    import jax
+    import jax.numpy as jnp
+
+    import run as harness
+
+    f = jax.jit(lambda x: x @ x)
+    x = jnp.ones((32, 32))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=harness._profile_options(jax))
+    try:
+        with jax.profiler.TraceAnnotation("bench:step", i=0):
+            with jax.profiler.TraceAnnotation("engine:step"):
+                with jax.profiler.TraceAnnotation("engine:decode", n=3):
+                    f(x).block_until_ready()
+            with jax.profiler.TraceAnnotation("spec:draft"):
+                jnp.sum(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    trace = xplane.load(xplane.find_xplane(str(tmp_path)))
+    assert [(s.name, s.args) for s in trace.spans] == [("step", {"i": 0})]
+    assert [s.name for s in trace.program_spans] == [
+        "engine:step", "engine:decode", "spec:draft"]
+    assert trace.program_spans[1].args == {"n": 3}
+    step = trace.spans[0]
+    assert all(step.start <= s.start <= s.end <= step.end for s in trace.program_spans)
+    assert xplane.window(trace) == (step.start, step.end)
+    assert [s.name for s in phases.engine_spans(trace)] == ["step", "decode"]

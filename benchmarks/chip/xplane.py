@@ -5,8 +5,11 @@ per chip, ``/device:TPU:<n>``, whose ``XLA Ops`` line holds one event per
 executed HLO instruction; the event's name is the instruction's text,
 ``%<name>.<id> = <shape> <opcode>(...)``, so a Pallas kernel appears under
 its instruction name (``splitk_gemm``, ``paged_splitk_flashattn``).  Host
-threads are on ``/host:CPU``; the harness's own ``TraceAnnotation`` spans
-(names starting ``bench:``) are there, on the same time base.
+threads are on ``/host:CPU``, on the same time base.  There the
+harness's own ``TraceAnnotation`` spans are named ``bench:<name>``, and the
+program's are named likewise ``<namespace>:<name>`` (``engine:decode``); the
+runtime's own host events are not (``PjitFunction(f)``, ``X::Y``,
+``end: op``).
 
 * busy: the union of a chip's op intervals inside the window, averaged
   over the chips;
@@ -27,6 +30,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OP_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench:"
+PROGRAM_SPAN = re.compile(r"^[A-Za-z_][\w.\-]*:[^\s:]")
 _INSTR = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)?\s*=")
 
 
@@ -56,6 +60,9 @@ class Span:
 class Trace:
     devices: list[list[Op]]      # per chip, sorted by start
     spans: list[Span]            # harness spans, sorted by start
+    # the program's annotations, every ``<namespace>:<name>`` host span but
+    # the harness's, name in full, sorted by start with outer before inner
+    program_spans: list[Span] = dataclasses.field(default_factory=list)
 
 
 def find_xplane(directory: str) -> str:
@@ -70,7 +77,7 @@ def load(path: str) -> Trace:
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
-    devices, spans = [], []
+    devices, spans, program = [], [], []
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
             ops = []
@@ -86,8 +93,12 @@ def load(path: str) -> Trace:
                     if e.name.startswith(SPAN_PREFIX):
                         spans.append(Span(e.name[len(SPAN_PREFIX):], e.start_ns,
                                           e.end_ns, dict(e.stats)))
+                    elif PROGRAM_SPAN.match(e.name):
+                        program.append(Span(e.name, e.start_ns, e.end_ns,
+                                            dict(e.stats)))
     spans.sort(key=lambda s: s.start)
-    return Trace(devices, spans)
+    program.sort(key=lambda s: (s.start, -s.end))
+    return Trace(devices, spans, program)
 
 
 def merge(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
